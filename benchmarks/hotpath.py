@@ -9,8 +9,9 @@ at SERVING batch shapes, across the three backends the wrappers dispatch to:
   * ``pallas_interpret`` — the Pallas kernel in interpreter mode (what CI and
     CPU runs of ``use_pallas=True`` execute; semantics of the compiled
     kernel, interpreter cost),
-  * ``pallas_compiled``  — the compiled Pallas kernel (TPU; recorded as
-    ``n/a`` where the platform cannot compile Pallas, e.g. CPU CI).
+  * ``pallas_compiled``  — the compiled Pallas kernel.  It runs only on a
+    TPU and its rows read ``n/a`` elsewhere.  On a TPU it is timed like the
+    others: a failure to compile or run fails the benchmark.
 
 Each kernel is timed on its two serving invocations: ``fwd`` (guard / predict
 rollouts) and ``grad`` (the refit train step's value_and_grad, which for the
@@ -18,8 +19,9 @@ Pallas backend runs the kernel forward + the reference backward via the
 custom-VJP rule — so `grad` rows price the full training hot path, not just
 the kernel).  Shapes mirror the 64-twin online benchmark (refit: 8 slots x 8
 windows, window 24; guard: budget-128 fused call, window 32) plus a 10k-scale
-guard shape.  Emitted to bench_out/hotpath.csv by ``benchmarks/run.py --only
-hotpath``.
+guard shape.  Every row names the platform, device kind and device count
+it ran on: a time from a CPU run is a CPU time, never a device number.
+Emitted to bench_out/hotpath.csv by ``benchmarks/run.py --only hotpath``.
 """
 from __future__ import annotations
 
@@ -32,36 +34,29 @@ from repro.kernels.gru.ops import gru_scan
 from repro.kernels.gru.ref import init_gru_params
 from repro.kernels.rk4.ops import rk4_poly_solve
 
-BACKENDS = [
-    # optional=True marks the backend that legitimately cannot run off-TPU
-    # (compiled Pallas) -> recorded as n/a; failures on the other two are
-    # real regressions and must fail the run (the CI smoke lane included).
-    ("reference", dict(use_pallas=False), False),
-    ("pallas_interpret", dict(use_pallas=True, interpret=True), False),
-    ("pallas_compiled", dict(use_pallas=True, interpret=False), True),
-]
+def _backends() -> list[tuple[str, dict | None]]:
+    """(name, wrapper kwargs); None where the backend cannot exist on this
+    platform — the compiled kernel off the TPU — and nothing else."""
+    on_tpu = jax.default_backend() == "tpu"
+    return [
+        ("reference", dict(use_pallas=False)),
+        ("pallas_interpret", dict(use_pallas=True, interpret=True)),
+        ("pallas_compiled",
+         dict(use_pallas=True, interpret=False) if on_tpu else None),
+    ]
 
 
-def _try_time(fn, grad_fn, optional: bool) \
-        -> tuple[float | None, float | None]:
-    """(fwd_ms, grad_ms); None only where an `optional` backend cannot run."""
-    try:
-        fwd = 1e3 * time_fn(fn)
-    except Exception as e:
-        if not optional:
-            raise
-        print(f"  [hotpath] backend unavailable ({type(e).__name__}): "
-              f"{str(e).splitlines()[0][:120]}")
+def _device_cols() -> dict:
+    d = jax.devices()[0]
+    return {"platform": d.platform, "device_kind": d.device_kind,
+            "devices": len(jax.devices())}
+
+
+def _time(fn, grad_fn, kw) -> tuple[float | None, float | None]:
+    """(fwd_ms, grad_ms); (None, None) for a backend this platform lacks."""
+    if kw is None:
         return None, None
-    try:
-        grad = 1e3 * time_fn(grad_fn)
-    except Exception as e:
-        if not optional:
-            raise
-        print(f"  [hotpath] grad unavailable ({type(e).__name__}): "
-              f"{str(e).splitlines()[0][:120]}")
-        grad = None
-    return fwd, grad
+    return 1e3 * time_fn(fn), 1e3 * time_fn(grad_fn)
 
 
 def _gru_rows(B, T, D, H, tag):
@@ -70,7 +65,7 @@ def _gru_rows(B, T, D, H, tag):
     xs = jax.random.normal(key, (B, T, D))
     h0 = jnp.zeros((B, H))
     rows = []
-    for name, kw, optional in BACKENDS:
+    for name, kw in _backends():
         def loss(wx):
             hs, hT = gru_scan(xs, h0, wx, p["wh"], p["b"], **kw)
             return jnp.sum(hT ** 2) + jnp.mean(hs ** 2)
@@ -85,9 +80,9 @@ def _gru_rows(B, T, D, H, tag):
         def grad():
             return grad_fn(p["wx"])
 
-        fwd_ms, grad_ms = _try_time(fwd, grad, optional)
+        fwd_ms, grad_ms = _time(fwd, grad, kw)
         rows.append({"op": "gru_scan", "shape": tag,
-                     "B": B, "T": T, "backend": name,
+                     "B": B, "T": T, "backend": name, **_device_cols(),
                      "fwd_ms": _fmt(fwd_ms), "grad_ms": _fmt(grad_ms)})
     return rows
 
@@ -99,7 +94,7 @@ def _rk4_rows(B, T, n, m, order, tag):
     y0 = 0.3 * jax.random.normal(key, (B, n))
     us = 0.2 * jax.random.normal(key, (B, T, m))
     rows = []
-    for name, kw, optional in BACKENDS:
+    for name, kw in _backends():
         def loss(th):
             ys = rk4_poly_solve(th, y0, us, dt=0.02, library=lib, **kw)
             return jnp.mean(ys ** 2)
@@ -112,9 +107,9 @@ def _rk4_rows(B, T, n, m, order, tag):
         def grad():
             return grad_fn(theta)
 
-        fwd_ms, grad_ms = _try_time(fwd, grad, optional)
+        fwd_ms, grad_ms = _time(fwd, grad, kw)
         rows.append({"op": "rk4_roll", "shape": tag,
-                     "B": B, "T": T, "backend": name,
+                     "B": B, "T": T, "backend": name, **_device_cols(),
                      "fwd_ms": _fmt(fwd_ms), "grad_ms": _fmt(grad_ms)})
     return rows
 
@@ -143,8 +138,10 @@ def run(quick: bool = True, smoke: bool = False) -> None:
         rows += _gru_rows(B, T, D, H, tag)
     for B, T, n, m, order, tag in shapes_rk4:
         rows += _rk4_rows(B, T, n, m, order, tag)
+    dev = _device_cols()
     print_rows("serving hot path: reference vs pallas backends "
-               f"(platform={jax.default_backend()})", rows)
+               f"(platform={dev['platform']}, device_kind="
+               f"{dev['device_kind']}, devices={dev['devices']})", rows)
     path = write_csv("hotpath.csv", rows)
     print(f"[hotpath] wrote {path}")
 

@@ -29,12 +29,19 @@ producers from the serving loop), and the in-process baseline runs sync
 ingest too — the comparison is contention-free by construction on any
 host.  Checkpoint/journal machinery is OFF in the throughput rows and ON
 in the kill row (its cost is benchmarked separately in online_chaos.csv).
+
+The process running this module is the coordinator, and a chip belongs to
+one process: it keeps its own JAX on the CPU (telemetry is generated there)
+and runs the in-process baseline in a spawned process of its own, so the
+workers and the baseline each get the chips.
 """
 from __future__ import annotations
 
+import multiprocessing as mp
 import os
 import shutil
 import tempfile
+from concurrent.futures import ProcessPoolExecutor
 
 import jax
 import numpy as np
@@ -43,9 +50,11 @@ from benchmarks.common import print_rows, write_csv
 from repro.core.merinda import MerindaConfig
 from repro.systems.f8_crusader import F8Crusader
 from repro.systems.simulate import simulate_batch
+from repro.compile_cache import enable_compile_cache
 from repro.twin import (ChaosConfig, FederatedTwinConfig, FederatedTwinServer,
                         FrontDoorClient, GuardConfig, RecoveryConfig,
                         ShardedTwinConfig, ShardedTwinServer, TwinServerConfig)
+from repro.twin.federation import pin_host_jax_to_cpu
 
 CHUNK = 8           # telemetry samples per twin per tick
 GUARD_BUDGET = 128  # per-worker rotating guard subset
@@ -82,14 +91,32 @@ def _row(scenario, mode, n_twins, workers, ingest, s, deadline_s) -> dict:
     }
 
 
+def _telemetry(n_twins: int, ticks: int, seed: int):
+    """(ys, us) host arrays for WARMUP + ticks serving ticks."""
+    sim = simulate_batch(F8Crusader(), jax.random.PRNGKey(seed),
+                         batch=n_twins, horizon=CHUNK * (WARMUP + ticks) + 1,
+                         noise_std=0.002)
+    return np.asarray(sim.ys_noisy), np.asarray(sim.us)
+
+
 def _serve(mode: str, n_twins: int, workers: int, ticks: int, *,
            tcp: bool = False, seed: int = 0) -> dict:
-    """One throughput run: identical protocol call sites for both modes."""
+    """One throughput run: identical protocol call sites for both modes.
+    The in-process mode runs in a process of its own (see module doc)."""
+    ys, us = _telemetry(n_twins, ticks, seed)
+    if mode == "inproc":
+        with ProcessPoolExecutor(
+                max_workers=1, mp_context=mp.get_context("spawn")) as ex:
+            return ex.submit(_serve_telemetry, mode, ys, us, n_twins,
+                             workers, ticks, tcp=tcp, seed=seed).result()
+    return _serve_telemetry(mode, ys, us, n_twins, workers, ticks, tcp=tcp,
+                            seed=seed)
+
+
+def _serve_telemetry(mode: str, ys, us, n_twins: int, workers: int,
+                     ticks: int, *, tcp: bool = False, seed: int = 0) -> dict:
+    enable_compile_cache()     # a spawned baseline starts without it
     system = F8Crusader()
-    horizon = CHUNK * (WARMUP + ticks) + 1
-    sim = simulate_batch(system, jax.random.PRNGKey(seed), batch=n_twins,
-                         horizon=horizon, noise_std=0.002)
-    ys, us = np.asarray(sim.ys_noisy), np.asarray(sim.us)
     scfg = _shard_cfg(system, n_twins, workers, seed=seed)
     if mode == "federated":
         srv = FederatedTwinServer(FederatedTwinConfig.uniform(
@@ -127,10 +154,7 @@ def _serve_kill(n_twins: int, workers: int, ticks: int, *,
     supervised restart after 1 tick, journal-tail replay.  Deadline 5 s so
     the restore tick (process boot + compile) is reported, not flaky."""
     system = F8Crusader()
-    horizon = CHUNK * (WARMUP + ticks) + 1
-    sim = simulate_batch(system, jax.random.PRNGKey(seed), batch=n_twins,
-                         horizon=horizon, noise_std=0.002)
-    ys, us = np.asarray(sim.ys_noisy), np.asarray(sim.us)
+    ys, us = _telemetry(n_twins, ticks, seed)
     scfg = _shard_cfg(system, n_twins, workers, seed=seed, deadline_s=5.0)
     victim = workers - 1
     kill_tick = WARMUP + max(2, ticks // 3)
@@ -232,6 +256,7 @@ def _kill_verdict(row: dict) -> None:
 
 
 def run(quick: bool = True, smoke: bool = False) -> None:
+    pin_host_jax_to_cpu()
     if smoke:
         sweeps = [("inproc", 256, 2, 6, False), ("federated", 256, 2, 6,
                                                  False)]

@@ -59,6 +59,24 @@ WARMUP = 18        # ticks excluded from stats: jit compile, slot fill, and
                    # the first deploy/promote compilations all land in warmup
 
 
+def scale_config(system, n_twins: int, shards: int, *,
+                 guard_budget: int = GUARD_BUDGET, seed: int = 0,
+                 use_pallas: bool = False, **kw) -> TwinServerConfig:
+    """One shard of the `online_scale` deployment (hidden 16, window 16,
+    guard window 24, budgeted guard); `kw` overrides serving fields.  Also
+    what chip_smoke.py phase (b) serves."""
+    per_shard = -(-n_twins // shards)
+    return TwinServerConfig(
+        merinda=MerindaConfig(n=system.spec.n, m=system.spec.m, order=3,
+                              dt=system.spec.dt, hidden=16, head_hidden=16,
+                              n_active=24, use_pallas=use_pallas),
+        max_twins=per_shard, refit_slots=8,
+        capacity=64, window=16, stride=8, windows_per_twin=4,
+        steps_per_tick=1, deploy_after=8, min_residency=4, max_residency=16,
+        guard=GuardConfig(window=24),
+        guard_budget=min(guard_budget, per_shard), seed=seed, **kw)
+
+
 def _serve_scale(n_twins: int, shards: int, ticks: int, *,
                  guard_budget: int = GUARD_BUDGET, seed: int = 0,
                  trace: bool = False, sync: bool = False) -> dict:
@@ -68,17 +86,8 @@ def _serve_scale(n_twins: int, shards: int, ticks: int, *,
                          horizon=horizon, noise_std=0.002)
     ys, us = np.asarray(sim.ys_noisy), np.asarray(sim.us)
 
-    per_shard = -(-n_twins // shards)
-    scfg = TwinServerConfig(
-        merinda=MerindaConfig(n=system.spec.n, m=system.spec.m, order=3,
-                              dt=system.spec.dt, hidden=16, head_hidden=16,
-                              n_active=24),
-        max_twins=per_shard, refit_slots=8,
-        capacity=64, window=16, stride=8, windows_per_twin=4,
-        steps_per_tick=1, deploy_after=8, min_residency=4, max_residency=16,
-        guard=GuardConfig(window=24),
-        guard_budget=min(guard_budget, per_shard),
-        async_ingest=not sync, seed=seed)
+    scfg = scale_config(system, n_twins, shards, guard_budget=guard_budget,
+                        seed=seed, async_ingest=not sync)
     tracer = Tracer(sample_every=1) if trace else None
     srv = ShardedTwinServer(ShardedTwinConfig.uniform(
         scfg, shards, rebalance_every=4), tracer=tracer)
@@ -205,20 +214,11 @@ def _serve_chaos(scenario: str, n_twins: int, shards: int, ticks: int, *,
                          horizon=horizon, noise_std=0.002)
     ys, us = np.asarray(sim.ys_noisy), np.asarray(sim.us)
 
-    per_shard = -(-n_twins // shards)
-    scfg = TwinServerConfig(
-        merinda=MerindaConfig(n=system.spec.n, m=system.spec.m, order=3,
-                              dt=system.spec.dt, hidden=16, head_hidden=16,
-                              n_active=24),
-        max_twins=per_shard, refit_slots=8,
-        capacity=64, window=16, stride=8, windows_per_twin=4,
-        steps_per_tick=1, deploy_after=8, min_residency=4, max_residency=16,
-        guard=GuardConfig(window=24),
-        guard_budget=min(GUARD_BUDGET, per_shard),
-        deadline_s=deadline_s,
+    scfg = scale_config(
+        system, n_twins, shards, seed=seed, deadline_s=deadline_s,
         degradation=DegradationConfig(enabled=degradation, hold_ticks=1,
                                       alpha=0.9),
-        async_ingest=False, seed=seed)
+        async_ingest=False)
     ckpt_dir = tempfile.mkdtemp(prefix="twin_chaos_ckpt_")
     recovery = (RecoveryConfig(ckpt_dir=ckpt_dir, ckpt_every=ckpt_every)
                 if ckpt_every is not None else None)

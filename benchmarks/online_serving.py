@@ -28,6 +28,20 @@ WARMUP = 18        # ticks excluded from stats: jit compile, slot fill, and
                    # the first deploy/guard activations all land in warmup
 
 
+def online_config(system, n_twins: int, refit_slots: int, *, seed: int = 0,
+                  use_pallas: bool = False) -> TwinServerConfig:
+    """The `online` deployment: one TwinServer, hidden 32, window 24,
+    guard window 32 (also what chip_smoke.py phase (a) serves)."""
+    return TwinServerConfig(
+        merinda=MerindaConfig(n=system.spec.n, m=system.spec.m, order=3,
+                              dt=system.spec.dt, hidden=32, head_hidden=32,
+                              n_active=24, use_pallas=use_pallas),
+        max_twins=n_twins, refit_slots=refit_slots,
+        capacity=256, window=24, stride=8, windows_per_twin=8,
+        steps_per_tick=2, deploy_after=8, min_residency=4, max_residency=16,
+        guard=GuardConfig(window=32), seed=seed)
+
+
 def _serve(n_twins: int, refit_slots: int, ticks: int, seed: int = 0,
            use_pallas: bool = False) -> dict:
     system = F8Crusader()
@@ -36,15 +50,8 @@ def _serve(n_twins: int, refit_slots: int, ticks: int, seed: int = 0,
                            horizon=horizon, noise_std=0.002)
     ys, us = np.asarray(trace.ys_noisy), np.asarray(trace.us)
 
-    cfg = TwinServerConfig(
-        merinda=MerindaConfig(n=system.spec.n, m=system.spec.m, order=3,
-                              dt=system.spec.dt, hidden=32, head_hidden=32,
-                              n_active=24, use_pallas=use_pallas),
-        max_twins=n_twins, refit_slots=refit_slots,
-        capacity=256, window=24, stride=8, windows_per_twin=8,
-        steps_per_tick=2, deploy_after=8, min_residency=4, max_residency=16,
-        guard=GuardConfig(window=32), seed=seed)
-    srv = TwinServer(cfg)
+    srv = TwinServer(online_config(system, n_twins, refit_slots, seed=seed,
+                                   use_pallas=use_pallas))
 
     for t in range(WARMUP + ticks):
         lo = t * CHUNK

@@ -14,6 +14,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.fleet import FleetConfig, FleetMerinda
 from repro.core.merinda import MerindaConfig
 from repro.data.pipeline import make_windows
@@ -26,6 +27,7 @@ def main():
     ap.add_argument("--fleet", type=int, default=16)
     ap.add_argument("--steps", type=int, default=60)
     args = ap.parse_args()
+    enable_compile_cache()
 
     key = jax.random.PRNGKey(0)
     system = F8Crusader()

@@ -13,56 +13,20 @@ is accounted against the 1 s deadline (5x under the 5 s human-pilot
 reaction time).
 """
 import argparse
-import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.merinda import MerindaConfig
-from repro.core.odeint import integrate
-from repro.systems.f8_crusader import F8Crusader, _f8_rows
-from repro.systems.simulate import simulate_batch
+from repro.systems.f8_crusader import (DamagedF8, F8Crusader,
+                                       trim_neighborhood)
+from repro.systems.simulate import continue_batch, simulate_batch
 from repro.twin.monitor import GuardConfig
 from repro.twin.server import TwinServer, TwinServerConfig
 
 CHUNK = 8   # telemetry samples per twin per serving tick
-
-
-class DamagedF8(F8Crusader):
-    """F-8 with partial elevator loss: every input-dependent coefficient is
-    scaled by `effectiveness` — the control surface answers, but weakly."""
-
-    def __init__(self, effectiveness: float = 0.25):
-        super().__init__()
-        self.effectiveness = effectiveness
-
-    def rows(self):
-        rows = _f8_rows(0, self.spec.n, "u0")
-        return [{k: (v * self.effectiveness if "u0" in k else v)
-                 for k, v in row.items()} for row in rows]
-
-
-def trim_neighborhood(system, y0_frac: float = 0.5, input_scale: float = 0.03):
-    """Confine the scenario to the F-8's trim neighborhood: the open-loop
-    cubic terms (3.846 y0^3) depart controlled flight in finite time for
-    large angle-of-attack excursions, and a 7+ second open-loop stream from
-    the full y0 range reliably finds that boundary for a few airframes."""
-    system.spec = dataclasses.replace(
-        system.spec,
-        y0_low=tuple(v * y0_frac for v in system.spec.y0_low),
-        y0_high=tuple(v * y0_frac for v in system.spec.y0_high),
-        input_scale=input_scale)
-    return system
-
-
-def roll(system, y0s, us, noise_std, key):
-    """Continue each twin's trajectory under `system` from its own state."""
-    ys = jax.vmap(lambda y0, u: integrate(system.rhs, y0, u,
-                                          system.spec.dt, substeps=10))(y0s, us)
-    noise = noise_std * jax.random.normal(key, ys.shape) \
-        * jnp.std(ys, axis=1, keepdims=True)
-    return ys + noise
 
 
 def main():
@@ -73,6 +37,7 @@ def main():
     ap.add_argument("--pre-ticks", type=int, default=25)
     ap.add_argument("--post-ticks", type=int, default=45)
     args = ap.parse_args()
+    enable_compile_cache()
 
     key = jax.random.PRNGKey(0)
     nominal = trim_neighborhood(F8Crusader())
@@ -90,11 +55,11 @@ def main():
     us2 = jnp.transpose(
         nominal.sample_inputs(k_u, t2, batch=(n_tw,)), (1, 0, 2))
     y_end = tr.ys[:, -1, :]
-    ys2 = np.array(roll(nominal, y_end, us2, 0.002, k_n1))
+    ys2 = np.array(continue_batch(nominal, y_end, us2, k_n1, noise_std=0.002))
     if dmg_ids:
         idx = jnp.asarray(dmg_ids, jnp.int32)
-        ys2[dmg_ids] = np.asarray(
-            roll(damaged, y_end[idx], us2[idx], 0.002, k_n2))
+        ys2[dmg_ids] = np.asarray(continue_batch(
+            damaged, y_end[idx], us2[idx], k_n2, noise_std=0.002))
     ys = np.concatenate([np.asarray(tr.ys_noisy[:, :-1]), ys2[:, :-1]], 1)
     us = np.concatenate([np.asarray(tr.us), np.asarray(us2)], 1)
 

@@ -8,6 +8,7 @@ prints the recovered governing equations next to the ground truth.
 """
 import jax
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.merinda import Merinda, MerindaConfig
 from repro.core.trainer import fit
 from repro.data.pipeline import WindowDataset
@@ -16,6 +17,7 @@ from repro.systems.simulate import simulate_batch
 
 
 def main():
+    enable_compile_cache()
     key = jax.random.PRNGKey(0)
     system = LotkaVolterra()
     print("simulating traces...")

@@ -32,6 +32,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.merinda import MerindaConfig
 from repro.systems.f8_crusader import F8Crusader
 from repro.systems.grid_frequency import GridFrequency
@@ -86,6 +87,7 @@ def main():
     ap.add_argument("--horizon", type=int, default=40,
                     help="what-if lookahead steps")
     args = ap.parse_args()
+    enable_compile_cache()
 
     nf = args.per_family
     families = [

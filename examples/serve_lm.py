@@ -12,6 +12,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch
 from repro.models.zoo import build
 from repro.serve.engine import Request, ServeEngine
@@ -23,6 +24,7 @@ def main():
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--requests", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     api = build(get_arch(args.arch).smoke)
     params = api.init(jax.random.PRNGKey(0))

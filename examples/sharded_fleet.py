@@ -21,13 +21,14 @@ scores a rotating budget per tick, so the tick cost is bounded regardless of
 fleet size — the same architecture benchmarks/online_scale.py pushes to 10k.
 """
 import argparse
-import dataclasses
 
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.merinda import MerindaConfig
-from repro.systems.f8_crusader import F8Crusader, _f8_rows
+from repro.systems.f8_crusader import (DamagedF8, F8Crusader,
+                                       trim_neighborhood)
 from repro.systems.lotka_volterra import LotkaVolterra
 from repro.systems.simulate import simulate_batch
 from repro.systems.van_der_pol import VanDerPol
@@ -36,30 +37,6 @@ from repro.twin.server import TwinServerConfig
 from repro.twin.sharded import ShardedTwinConfig, ShardedTwinServer
 
 CHUNK = 8   # telemetry samples per twin per serving tick
-
-
-class DamagedF8(F8Crusader):
-    """F-8 with partial elevator loss (see examples/online_twinning.py)."""
-
-    def __init__(self, effectiveness: float = 0.25):
-        super().__init__()
-        self.effectiveness = effectiveness
-
-    def rows(self):
-        rows = _f8_rows(0, self.spec.n, "u0")
-        return [{k: (v * self.effectiveness if "u0" in k else v)
-                 for k, v in row.items()} for row in rows]
-
-
-def trim(system, y0_frac: float = 0.5, input_scale: float = 0.03):
-    """Confine the F-8 to its trim neighborhood (open-loop cubic terms
-    depart controlled flight for large excursions; see online_twinning)."""
-    system.spec = dataclasses.replace(
-        system.spec,
-        y0_low=tuple(v * y0_frac for v in system.spec.y0_low),
-        y0_high=tuple(v * y0_frac for v in system.spec.y0_high),
-        input_scale=input_scale)
-    return system
 
 
 def family_cfg(system, n_active: int, seed: int) -> TwinServerConfig:
@@ -85,9 +62,10 @@ def main():
                     help="ticks excluded from the latency report (jit "
                          "compile, slot fill, first promote compilation)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     nf = args.per_family
-    families = [("f8", trim(F8Crusader()), 24),
+    families = [("f8", trim_neighborhood(F8Crusader()), 24),
                 ("vdp", VanDerPol(), 12),
                 ("lv", LotkaVolterra(), 6)]
     horizon = CHUNK * args.ticks + 1
@@ -101,7 +79,7 @@ def main():
         tr = simulate_batch(system, jax.random.PRNGKey(i), batch=nf,
                             horizon=horizon, noise_std=0.002)
         telemetry.append([np.array(tr.ys_noisy), np.array(tr.us)])
-    dmg = trim(DamagedF8())
+    dmg = trim_neighborhood(DamagedF8())
     tr = simulate_batch(dmg, jax.random.PRNGKey(0), batch=args.damaged,
                         horizon=horizon, noise_std=0.002)
     telemetry[0][0][:args.damaged] = np.asarray(tr.ys_noisy)

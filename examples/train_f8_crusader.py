@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.merinda import Merinda, MerindaConfig
 from repro.core.trainer import fit
 from repro.data.pipeline import WindowDataset
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--steps", type=int, default=400)
     ap.add_argument("--ckpt-dir", default="/tmp/merinda_f8_ckpt")
     args = ap.parse_args()
+    enable_compile_cache()
 
     key = jax.random.PRNGKey(0)
     system = F8Crusader()

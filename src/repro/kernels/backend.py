@@ -17,6 +17,14 @@ server's guard said ``interpret=True`` while its config said otherwise).
 `resolve_interpret` is now the single source of truth; call sites pass
 ``None`` through and the decision happens here, once per process.
 
+`MATMUL_PRECISION` is the one matmul precision of the kernels and their
+references.  On a TPU, an f32 matmul at default precision is a single bf16
+pass: it keeps 8 bits of mantissa.  The RK4 kernel evaluates its library as
+a matmul against one-hot selection matrices, a gather that must be exact,
+and the references must round the way the kernels do, so both sides ask
+for full f32.  The matmuls are tiny ([tile, 1+n+m] x [1+n+m, L] and
+[tile, H] x [H, 3H]); the extra MXU passes cost little next to the scan.
+
 `bucket_pow2` is the companion shape policy: Pallas batch padding rounds the
 tile count up to a power of two, so the number of DISTINCT kernel shapes a
 varying batch axis can produce is log2-bounded — the same trade the
@@ -29,7 +37,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["resolve_interpret", "bucket_pow2", "pad_batch"]
+__all__ = ["MATMUL_PRECISION", "resolve_interpret", "bucket_pow2",
+           "pad_batch"]
+
+MATMUL_PRECISION = jax.lax.Precision.HIGHEST
 
 
 def resolve_interpret(interpret: bool | None) -> bool:

@@ -10,8 +10,14 @@ FPGA -> TPU mapping (DESIGN.md §2):
     candidate is a second [H, H] matmul; all gate elementwise math stays in
     registers (VPU) — no HBM round-trips between timesteps.
 
-Block shapes are padded to (8, 128) multiples by the wrapper (ops.py) so MXU
-matmul dims are hardware-aligned.
+The wrapper (ops.py) pads only the batch axis, to a pow2 multiple of the
+batch tile; T, Din and H reach the kernel at their true sizes.
+
+The hoisted projections are staged in a VMEM scratch ref and each timestep
+reads its row with a dynamic ref index.  A dynamic slice of a VALUE by the
+loop index does not lower on the TPU (Mosaic has no ``dynamic_slice``),
+while dynamic indexing of a ref does, and the loop stays rolled: code size
+does not grow with T.
 """
 from __future__ import annotations
 
@@ -20,12 +26,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import MATMUL_PRECISION
 
 __all__ = ["gru_scan_pallas"]
 
 
 def _gru_kernel(xs_ref, h0_ref, wx_ref, wh_ref, b_ref, hs_ref, hT_ref,
-                *, hidden: int, seq_len: int):
+                xp_ref, *, hidden: int, seq_len: int):
     """One batch tile: hoisted input matmul + fused recurrent scan."""
     H = hidden
     xs = xs_ref[...]                                  # [Bt, T, Din]
@@ -35,21 +44,23 @@ def _gru_kernel(xs_ref, h0_ref, wx_ref, wh_ref, b_ref, hs_ref, hT_ref,
     b = b_ref[...]                                    # [1, 3H]
 
     # --- Stage 1: hoist all T input projections into one MXU matmul. ------
-    xp = jnp.dot(xs.reshape(bt * T, d_in), wx,
+    xp = jnp.dot(xs.reshape(bt * T, d_in), wx, precision=MATMUL_PRECISION,
                  preferred_element_type=jnp.float32)
-    xp = (xp + b).reshape(bt, T, 3 * H)
+    xp_ref[...] = (xp + b).reshape(bt, T, 3 * H)
 
     wh_zr = wh[:, :2 * H]
     wh_c = wh[:, 2 * H:]
 
     # --- Stage 2: recurrent scan, weights resident in VMEM. ---------------
     def step(t, h):
-        xp_t = xp[:, t, :]                            # [Bt, 3H]
-        hp = jnp.dot(h, wh_zr, preferred_element_type=jnp.float32)
+        xp_t = xp_ref[:, t, :]                        # [Bt, 3H]
+        hp = jnp.dot(h, wh_zr, precision=MATMUL_PRECISION,
+                     preferred_element_type=jnp.float32)
         z = jax.nn.sigmoid(xp_t[:, :H] + hp[:, :H])
         r = jax.nn.sigmoid(xp_t[:, H:2 * H] + hp[:, H:])
         c = jnp.tanh(xp_t[:, 2 * H:]
-                     + jnp.dot(r * h, wh_c, preferred_element_type=jnp.float32))
+                     + jnp.dot(r * h, wh_c, precision=MATMUL_PRECISION,
+                               preferred_element_type=jnp.float32))
         h = (1.0 - z) * h + z * c
         hs_ref[:, t, :] = h.astype(hs_ref.dtype)
         return h
@@ -91,6 +102,8 @@ def gru_scan_pallas(xs, h0, wx, wh, b, *, block_b: int = 8,
             jax.ShapeDtypeStruct((B, T, H), xs.dtype),
             jax.ShapeDtypeStruct((B, H), h0.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((block_b, T, 3 * H), jnp.float32)],
         interpret=interpret,
+        name="gru_scan",
     )(xs, h0, wx, wh, b2)
     return hs, hT

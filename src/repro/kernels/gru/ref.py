@@ -16,7 +16,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import MATMUL_PRECISION
+
 __all__ = ["gru_cell_ref", "gru_scan_ref", "init_gru_params"]
+
+
+def _mm(a, b):
+    return jnp.matmul(a, b, precision=MATMUL_PRECISION)
 
 
 def init_gru_params(key, d_in: int, hidden: int, dtype=jnp.float32):
@@ -35,11 +41,11 @@ def init_gru_params(key, d_in: int, hidden: int, dtype=jnp.float32):
 def gru_cell_ref(h, x, wx, wh, b):
     """One GRU step. h: [..., H], x: [..., Din] -> new h."""
     H = h.shape[-1]
-    xp = x @ wx + b                                   # [..., 3H]
-    hp2 = h @ wh[:, :2 * H]                           # z/r hidden contribution
+    xp = _mm(x, wx) + b                               # [..., 3H]
+    hp2 = _mm(h, wh[:, :2 * H])                       # z/r hidden contribution
     z = jax.nn.sigmoid(xp[..., :H] + hp2[..., :H])
     r = jax.nn.sigmoid(xp[..., H:2 * H] + hp2[..., H:])
-    c = jnp.tanh(xp[..., 2 * H:] + (r * h) @ wh[:, 2 * H:])
+    c = jnp.tanh(xp[..., 2 * H:] + _mm(r * h, wh[:, 2 * H:]))
     return (1.0 - z) * h + z * c
 
 
@@ -51,13 +57,13 @@ def gru_scan_ref(xs, h0, wx, wh, b):
     # Hoisted input projection: one large MXU matmul for every timestep
     # (the TPU analogue of ARRAY_PARTITION; see DESIGN.md §2).
     H = h0.shape[-1]
-    xp = xs @ wx + b                                   # [B, T, 3H]
+    xp = _mm(xs, wx) + b                               # [B, T, 3H]
 
     def step(h, xp_t):
-        hp2 = h @ wh[:, :2 * H]
+        hp2 = _mm(h, wh[:, :2 * H])
         z = jax.nn.sigmoid(xp_t[..., :H] + hp2[..., :H])
         r = jax.nn.sigmoid(xp_t[..., H:2 * H] + hp2[..., H:])
-        c = jnp.tanh(xp_t[..., 2 * H:] + (r * h) @ wh[:, 2 * H:])
+        c = jnp.tanh(xp_t[..., 2 * H:] + _mm(r * h, wh[:, 2 * H:]))
         h = (1.0 - z) * h + z * c
         return h, h
 

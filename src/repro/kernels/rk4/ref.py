@@ -12,6 +12,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.backend import MATMUL_PRECISION
+
 __all__ = ["rk4_poly_solve_ref", "poly_features_ref"]
 
 
@@ -24,7 +26,8 @@ def poly_features_ref(y, u, term_indices):
 def rk4_poly_solve_ref(theta, y0, us, dt, term_indices):
     def rhs(y, u):
         phi = poly_features_ref(y, u, term_indices)          # [B, L]
-        return jnp.einsum("bnl,bl->bn", theta, phi)
+        return jnp.einsum("bnl,bl->bn", theta, phi,
+                          precision=MATMUL_PRECISION)
 
     def step(y, u):
         k1 = rhs(y, u)

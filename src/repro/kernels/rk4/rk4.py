@@ -22,6 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import MATMUL_PRECISION
+
 __all__ = ["rk4_poly_solve_pallas", "selection_matrices"]
 
 
@@ -46,7 +48,7 @@ def _rk4_kernel(theta_ref, y0_ref, us_ref, sel_ref, ys_ref,
         xaug = jnp.concatenate([ones, y, u], axis=-1)    # [Bt, 1+n+m]
         phi = jnp.ones((bt, L), jnp.float32)
         for o in range(order):                           # static unroll
-            phi = phi * jnp.dot(xaug, sel[o],
+            phi = phi * jnp.dot(xaug, sel[o], precision=MATMUL_PRECISION,
                                 preferred_element_type=jnp.float32)
         return jnp.sum(phi[:, None, :] * theta, axis=-1)  # [Bt, n]
 
@@ -89,5 +91,6 @@ def rk4_poly_solve_pallas(theta, y0, us, dt, sel, *, block_b: int = 8,
         out_specs=pl.BlockSpec((block_b, T + 1, n), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, T + 1, n), theta.dtype),
         interpret=interpret,
+        name="rk4_poly_solve",
     )(theta, y0, us, sel)
     return ys

@@ -18,6 +18,8 @@ dynamics sparse and identifiable.
 """
 from __future__ import annotations
 
+import dataclasses
+
 from repro.systems.base import DynamicalSystem, SystemSpec
 
 
@@ -73,3 +75,35 @@ class F8Crusader(DynamicalSystem):
         for k in range(self.n_aircraft):
             rows.extend(_f8_rows(3 * k, self.spec.n, u_name))
         return rows
+
+
+class DamagedF8(F8Crusader):
+    """One F-8 airframe with partial elevator loss: every input-dependent
+    coefficient is scaled by `effectiveness` — the control surface answers,
+    but weakly.  The online-twinning scenarios fly it while serving the
+    nominal model, so the divergence guard has a real dynamics change to
+    catch."""
+
+    def __init__(self, effectiveness: float = 0.25):
+        super().__init__()
+        self.effectiveness = effectiveness
+
+    def rows(self):
+        rows = _f8_rows(0, self.spec.n, "u0")
+        return [{k: (v * self.effectiveness if "u0" in k else v)
+                 for k, v in row.items()} for row in rows]
+
+
+def trim_neighborhood(system: F8Crusader, y0_frac: float = 0.5,
+                      input_scale: float = 0.03) -> F8Crusader:
+    """Confine `system` to the F-8's trim neighborhood (in place; returns
+    it): the open-loop cubic terms (3.846 y0^3) depart controlled flight in
+    finite time for large angle-of-attack excursions, and a long open-loop
+    stream from the full y0 range reliably finds that boundary for a few
+    airframes."""
+    system.spec = dataclasses.replace(
+        system.spec,
+        y0_low=tuple(v * y0_frac for v in system.spec.y0_low),
+        y0_high=tuple(v * y0_frac for v in system.spec.y0_high),
+        input_scale=input_scale)
+    return system

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from repro.core.odeint import integrate
 from repro.systems.base import DynamicalSystem
 
-__all__ = ["Trace", "simulate", "simulate_batch"]
+__all__ = ["Trace", "simulate", "simulate_batch", "continue_batch"]
 
 
 @dataclass
@@ -55,6 +55,19 @@ def simulate_batch(system: DynamicalSystem, key, batch: int,
     sim = jax.vmap(lambda k: _simulate(system, k, horizon, substeps, noise_std))
     ys, ys_noisy, us = sim(keys)
     return Trace(ys=ys, ys_noisy=ys_noisy, us=us, dt=system.spec.dt)
+
+
+def continue_batch(system: DynamicalSystem, y0s, us, key,
+                   noise_std: float = 0.0, substeps: int = 10):
+    """Continue each trajectory under `system` from its own state: y0s
+    [B, n], us [B, T, m] -> noisy ys [B, T+1, n] (noise scaled per channel
+    like `simulate`).  A mid-stream dynamics change is the nominal trace up
+    to the switch, then this under the changed system."""
+    ys = jax.vmap(lambda y0, u: integrate(system.rhs, y0, u, system.spec.dt,
+                                          substeps=substeps))(y0s, us)
+    noise = noise_std * jax.random.normal(key, ys.shape) \
+        * jnp.std(ys, axis=1, keepdims=True)
+    return ys + noise
 
 
 REGISTRY = {}

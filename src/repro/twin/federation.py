@@ -50,18 +50,30 @@ Worker boot is NOT cheap (a fresh JAX import + module compile, seconds,
 plus `restart_delay_ticks`); size `RecoveryConfig.journal_horizon` to
 cover the boot window at your ingest rate or replay will report lost
 samples.
+
+One process per chip: a TPU chip belongs to one process at a time.  On a
+TPU host, worker `i` is confined to chip `i` (libtpu's per-process chip
+visibility, set in the worker before its first JAX call), and a
+federation with more workers than chips is refused at construction.  The
+coordinator counts chips from the PCI bus and never initializes a TPU
+backend itself: a process that did would hold every chip of the host.
 """
 from __future__ import annotations
 
+import glob
 import multiprocessing as mp
+import os
 import threading
 import time
 import traceback
 from collections import deque
 from dataclasses import dataclass
 
+import jax
 import numpy as np
+from jax._src import hardware_utils
 
+from repro.compile_cache import enable_compile_cache
 from repro.obs import MetricRegistry, Tracer
 from repro.twin.monitor import GuardEvent
 from repro.twin.recovery import TelemetryJournal, TwinCheckpointer, \
@@ -74,7 +86,59 @@ from repro.twin.service import FleetTopologyConfig
 from repro.twin import wire as W
 
 __all__ = ["FederatedTwinConfig", "ShardWorker", "FederationCoordinator",
-           "FederatedTwinServer"]
+           "FederatedTwinServer", "pin_host_jax_to_cpu",
+           "tpu_chips_for_workers"]
+
+_TPU_PORT_BASE = 8476     # libtpu's default slice port; chip i uses base + i
+
+
+def _tpu_device_nodes() -> int:
+    """TPU device files this process may open (/dev/accel* on older
+    chips, /dev/vfio/<group> on v5e and later)."""
+    accel = glob.glob("/dev/accel*")
+    vfio = [p for p in glob.glob("/dev/vfio/*")
+            if os.path.basename(p) != "vfio"]
+    return len(accel) or len(vfio)
+
+
+def tpu_chips_for_workers() -> int | None:
+    """TPU chips the workers of this host share, or None when they run off
+    the TPU (``JAX_PLATFORMS`` without ``tpu``, or no usable chip).
+
+    Counted without loading the TPU runtime: chips on the PCI bus (JAX's
+    own probe), capped by the device files present, since a container can
+    see every chip of its host on the bus and be given only some of them.
+    Workers inherit the environment, not ``jax.config``: a parent that pins
+    its own JAX to the CPU still has TPU workers."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return None
+    on_bus, _ = hardware_utils.num_available_tpu_chips_and_device_id()
+    return min(on_bus, _tpu_device_nodes()) or None
+
+
+def pin_host_jax_to_cpu() -> None:
+    """Keep this process's own JAX work (telemetry generation, say) on the
+    CPU, so that it never holds a chip its workers need.  Call before the
+    process's first JAX operation; raises if an accelerator is already
+    held."""
+    jax.config.update("jax_platforms", "cpu")
+    if jax.default_backend() != "cpu":
+        raise RuntimeError("this process already initialized an "
+                           f"accelerator ({jax.default_backend()}); its "
+                           "workers could not have the chips")
+
+
+def _one_chip_env(chip: int) -> dict[str, str]:
+    """libtpu settings that make this process a one-chip slice on `chip`:
+    it loads libtpu for that chip alone, next to workers on the others."""
+    port = str(_TPU_PORT_BASE + chip)
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+            "TPU_PROCESS_PORT": port,
+            "CLOUD_TPU_TASK_ID": "0"}
 
 
 @dataclass(frozen=True)
@@ -102,13 +166,19 @@ class FederatedTwinConfig(FleetTopologyConfig):
 # --------------------------------------------------------------------------- #
 # worker process entry (module-level: spawn must import it by name)
 # --------------------------------------------------------------------------- #
-def _worker_main(conn, scfg: TwinServerConfig, shard: int, recovery) -> None:
+def _worker_main(conn, scfg: TwinServerConfig, shard: int, recovery,
+                 chip: int | None) -> None:
     """One `ShardWorker` subprocess: TwinServer + its checkpointer behind a
-    wire-message loop.  Boot: build, restore the newest committed
-    checkpoint, announce holdings in `Hello`.  Any command that raises
-    sends `ErrorMsg` and exits — the coordinator treats that as a death
-    and runs the restart protocol."""
+    wire-message loop.  Boot: confine the process to its TPU chip (if
+    any), build, restore the newest committed checkpoint, announce
+    holdings and device in `Hello`.  Any command that raises sends
+    `ErrorMsg` and exits — the coordinator treats that as a death and runs
+    the restart protocol."""
+    if chip is not None:
+        os.environ.update(_one_chip_env(chip))   # before the first JAX call
+    enable_compile_cache()
     srv = TwinServer(scfg, seed=scfg.seed + shard)
+    dev = jax.devices()[0]
     ckpt = TwinCheckpointer(recovery, metrics=srv.metrics) \
         if recovery is not None else None
     ckpt_tick = None
@@ -120,7 +190,11 @@ def _worker_main(conn, scfg: TwinServerConfig, shard: int, recovery) -> None:
                for tid, rec in srv.twin_snapshot().items()}
     conn.send_bytes(W.encode(W.Hello(
         shard=shard, tick=int(srv.tick_count), ckpt_tick=ckpt_tick,
-        samples=samples)))
+        samples=samples,
+        device={"platform": dev.platform, "kind": dev.device_kind,
+                "id": int(dev.id),
+                "coords": [int(c) for c in getattr(dev, "coords", ())],
+                "chip": chip})))
     last_saved = ckpt_tick
     try:
         while True:
@@ -230,11 +304,12 @@ class ShardWorker:
     hold `_send_lock` (front-door threads ingest concurrently with the
     serving thread); only the serving thread ever receives."""
 
-    def __init__(self, ctx, scfg: TwinServerConfig, shard: int, recovery):
+    def __init__(self, ctx, scfg: TwinServerConfig, shard: int, recovery,
+                 chip: int | None):
         self.shard = shard
         self.conn, child = ctx.Pipe(duplex=True)
         self.proc = ctx.Process(
-            target=_worker_main, args=(child, scfg, shard, recovery),
+            target=_worker_main, args=(child, scfg, shard, recovery, chip),
             name=f"twin-worker-{shard}", daemon=True)
         self.proc.start()
         child.close()                       # the worker owns its end now
@@ -312,6 +387,14 @@ class FederationCoordinator:
                  tracer: Tracer | None = None):
         if not cfg.servers:
             raise ValueError("need at least one worker")
+        chips = tpu_chips_for_workers()
+        if chips is not None and len(cfg.servers) > chips:
+            raise ValueError(
+                f"{len(cfg.servers)} workers need one TPU chip each, but "
+                f"this host has {chips}: a chip belongs to one process")
+        # worker i owns chip i (kept across restarts); None off the TPU
+        self._chips = (list(range(len(cfg.servers))) if chips is not None
+                       else [None] * len(cfg.servers))
         self.cfg = cfg
         self.metrics = MetricRegistry() if metrics is None else metrics
         self.tracer = Tracer(enabled=False) if tracer is None else tracer
@@ -332,7 +415,7 @@ class FederationCoordinator:
         self._instruments()
         t0 = time.perf_counter()
         self.workers: list[ShardWorker] = [
-            ShardWorker(self._ctx, scfg, i, cfg.recovery)
+            ShardWorker(self._ctx, scfg, i, cfg.recovery, self._chips[i])
             for i, scfg in enumerate(cfg.servers)]
         for w in self.workers:
             w.wait_hello(cfg.boot_timeout_s)
@@ -641,7 +724,7 @@ class FederationCoordinator:
         them, drain.  Returns the restart record for the tick report."""
         t0 = time.perf_counter()
         w = ShardWorker(self._ctx, self.cfg.servers[i], i,
-                        self.cfg.recovery)
+                        self.cfg.recovery, self._chips[i])
         hello = w.wait_hello(self.cfg.boot_timeout_s)
         self._m_boot.observe(time.perf_counter() - t0)
         self.workers[i] = w

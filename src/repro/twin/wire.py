@@ -80,6 +80,9 @@ class Hello:
     tick: int = 0                      # worker's restored tick counter
     ckpt_tick: int | None = None       # checkpoint tick it restored from
     samples: dict = field(default_factory=dict)   # twin_id(str) -> count
+    # the worker's JAX device: platform, kind, id, coords, and the host chip
+    # it was confined to (None off the TPU)
+    device: dict = field(default_factory=dict)
 
 
 @_message

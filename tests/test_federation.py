@@ -85,6 +85,62 @@ def test_hello_sample_keys_stringify_over_json():
     assert {int(k): int(v) for k, v in out.samples.items()} == {5: 10}
 
 
+def test_hello_device_roundtrip_and_default():
+    dev = {"platform": "tpu", "kind": "TPU v5 lite", "id": 0,
+           "coords": [1, 0, 0], "chip": 1}
+    assert W.decode(W.encode(W.Hello(shard=1, device=dev))).device == dev
+    assert W.decode(W.encode(W.Hello(shard=1))).device == {}
+
+
+# --------------------------------------------------------------------------- #
+# one process per chip (no subprocesses: refused before any spawn)
+# --------------------------------------------------------------------------- #
+@pytest.mark.parametrize("workers,chips", [(2, 1), (5, 4)])
+def test_coordinator_refuses_more_workers_than_chips(monkeypatch, workers,
+                                                     chips):
+    from repro.twin import federation
+    monkeypatch.setattr(federation, "tpu_chips_for_workers", lambda: chips)
+    spawned = []
+    monkeypatch.setattr(federation, "ShardWorker",
+                        lambda *a, **k: spawned.append(a))
+    cfg = FederatedTwinConfig.uniform(_worker_cfg(LotkaVolterra()), workers)
+    with pytest.raises(ValueError, match="one TPU chip each"):
+        FederatedTwinServer(cfg)
+    assert spawned == []
+
+
+def test_chip_count_follows_worker_platform(monkeypatch):
+    """Off-TPU platforms never limit workers; on TPU the chips this host
+    can open do, and counting never initializes a backend."""
+    from repro.twin import federation
+
+    def host(on_bus, nodes):
+        monkeypatch.setattr(federation.hardware_utils,
+                            "num_available_tpu_chips_and_device_id",
+                            lambda: (on_bus, None))
+        monkeypatch.setattr(federation, "_tpu_device_nodes", lambda: nodes)
+
+    host(4, 4)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert federation.tpu_chips_for_workers() is None
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    assert federation.tpu_chips_for_workers() == 4
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert federation.tpu_chips_for_workers() == 4
+    host(4, 1)          # a container given one chip of a four-chip host
+    assert federation.tpu_chips_for_workers() == 1
+    host(0, 0)
+    assert federation.tpu_chips_for_workers() is None
+
+
+def test_one_chip_env_confines_worker_to_its_chip():
+    from repro.twin.federation import _one_chip_env
+    envs = [_one_chip_env(i) for i in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert all(e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1" for e in envs)
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 4
+
+
 def test_decode_rejects_foreign_version():
     payload = bytearray(W.encode(W.Ack(n=1)))
     payload[:2] = struct.pack(">H", W.WIRE_VERSION + 1)
@@ -166,6 +222,9 @@ def test_federated_serves_through_the_protocol(fed_srv, lv_world):
     s = fed_srv.latency_summary()
     assert s["ticks"] >= 4 and s["dropped_samples"] == 0
     assert set(fed_srv.snapshot_state()) == {"shard0", "shard1"}
+    devices = [w.hello.device for w in fed_srv.workers]
+    assert [d["platform"] for d in devices] == ["cpu"] * WORKERS
+    assert [d["chip"] for d in devices] == [None] * WORKERS
 
 
 def test_predict_refusal_leaves_worker_alive(fed_srv, lv_world):
