@@ -1,0 +1,6 @@
+"""100 x (1 - union of device operation intervals / traced window)."""
+
+
+def read(ctx):
+    w = ctx.trace.window_s
+    return 100.0 * (1.0 - ctx.trace.busy_s / w) if w > 0 else None

@@ -1,0 +1,5 @@
+"""The program's `flush` spans, summed over shards, mean per traced tick, ms."""
+
+
+def read(ctx):
+    return ctx.span_ms_per_tick("flush")
