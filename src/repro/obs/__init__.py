@@ -2,6 +2,8 @@
 
 Dependency-free (stdlib only — no JAX, no numpy) so it can be imported from
 any layer, including host-side threads that must never touch device state.
+The tracer mirrors its spans into the JAX profiler when JAX is there, and
+imports it only when the first span is recorded.
 
 Modules
 -------
@@ -12,11 +14,14 @@ registry.py   `MetricRegistry` — thread-safe counters / gauges / fixed-bucket
               histograms are O(buckets) no matter how long the server runs.
 
 tracing.py    `Tracer` — nested spans around the serving stages
-              (tick -> flush/guard/schedule/refit, pump flushes, per-shard
-              ticks), recorded into a ring-bounded buffer and exported as
-              Chrome trace-event JSON loadable in Perfetto.  `sample_every`
-              records every Nth root span's subtree; `enabled=False` makes
-              spans no-op context managers (near-free).
+              (tick -> flush/guard/schedule/refit and their children,
+              `sync` spans around host reads of device values, pump
+              flushes, per-shard ticks), recorded into a ring-bounded
+              buffer and exported as Chrome trace-event JSON loadable in
+              Perfetto, and mirrored as `<cat>/<name>` profiler
+              annotations.  `sample_every` records every Nth root span's
+              subtree; `enabled=False` makes spans no-op context managers
+              (near-free).
 
 exporters.py  `SnapshotWriter` — periodic (atomic) JSON snapshot file of the
               registry, for deployments without scrape infrastructure.
@@ -28,10 +33,10 @@ from repro.obs.exporters import SnapshotWriter
 from repro.obs.registry import (Counter, Gauge, Histogram, MetricRegistry,
                                 DEFAULT_LATENCY_BUCKETS,
                                 DEFAULT_SCORE_BUCKETS, log_buckets)
-from repro.obs.tracing import NULL_SPAN, Tracer
+from repro.obs.tracing import NULL_SPAN, Tracer, null_span
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricRegistry", "log_buckets",
     "DEFAULT_LATENCY_BUCKETS", "DEFAULT_SCORE_BUCKETS",
-    "Tracer", "NULL_SPAN", "SnapshotWriter",
+    "Tracer", "NULL_SPAN", "null_span", "SnapshotWriter",
 ]

@@ -6,12 +6,26 @@ and WHICH stage.  `Tracer.span()` wraps the serving stages in nested spans —
     sharded_tick
     └─ tick (shard=0)
        ├─ flush            (+ pump_flush spans on the BackgroundPump thread)
+       │  └─ apply
        ├─ guard
        ├─ schedule
+       │  ├─ plan
+       │  └─ admit
        └─ refit
+          ├─ train
+          └─ promote
 
-— recorded as Chrome trace-event "complete" events (`ph: "X"`) that load
-directly in Perfetto (https://ui.perfetto.dev) or `chrome://tracing`.
+with a `sync` span (arg `site`) around every host read of a device value
+(docs/OBSERVABILITY.md lists the sites) — recorded as Chrome trace-event
+"complete" events (`ph: "X"`) that load directly in Perfetto
+(https://ui.perfetto.dev) or `chrome://tracing`.
+
+Every recorded span is also a `jax.profiler.TraceAnnotation` named
+`<cat>/<name>` (`twin/guard`, `ingest/pump_flush`, ...) with the span's args
+as metadata, so under the JAX profiler the spans sit on the host plane of the
+`.xplane.pb`, on the same clock as the device's operations.  JAX is imported
+on the first recorded span, never at import time; without JAX the spans are
+still recorded, only not mirrored.
 
 Designed for an always-on service:
 
@@ -23,8 +37,9 @@ Designed for an always-on service:
     sampled ticks stay internally complete (a half-recorded tick is useless);
   * **near-free when off** — `enabled=False` makes `span()` return a shared
     no-op context manager: no clock reads, no allocation, one attribute
-    check.  The 64-twin tracing-on-vs-off parity test and the 10k-twin
-    overhead column in bench_out/online_scale.csv hold the cost honest.
+    check, no annotation.  The 64-twin tracing-on-vs-off parity test and
+    the 10k-twin overhead column in bench_out/online_scale.csv hold the
+    cost honest.
 
 Spans may begin on any thread (the pump flush records from its worker
 thread); each thread renders as its own Perfetto track via `tid`, with
@@ -37,7 +52,7 @@ import threading
 import time
 from collections import deque
 
-__all__ = ["Tracer", "NULL_SPAN"]
+__all__ = ["Tracer", "NULL_SPAN", "null_span"]
 
 
 class _NullSpan:
@@ -53,6 +68,26 @@ class _NullSpan:
 
 
 NULL_SPAN = _NullSpan()
+
+
+def null_span(*_args, **_kwargs):
+    """`Tracer.span`'s stand-in where no tracer is attached."""
+    return NULL_SPAN
+
+
+_annotation = None      # jax.profiler.TraceAnnotation, bound on first use
+
+
+def _profiler_annotation():
+    """The profiler's annotation type, or a no-op where JAX is absent."""
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            TraceAnnotation = null_span
+        _annotation = TraceAnnotation
+    return _annotation
 
 
 class _SkipSpan:
@@ -75,9 +110,10 @@ class _SkipSpan:
 
 
 class _Span:
-    """One recorded span: clock on enter, event emission on exit."""
+    """One recorded span: profiler annotation and clock on enter, event
+    emission on exit."""
 
-    __slots__ = ("_tr", "_tls", "name", "cat", "args", "_t0")
+    __slots__ = ("_tr", "_tls", "name", "cat", "args", "_t0", "_ann")
 
     def __init__(self, tracer, tls, name, cat, args):
         self._tr = tracer
@@ -88,11 +124,15 @@ class _Span:
 
     def __enter__(self):
         self._tls.depth += 1
+        self._ann = _profiler_annotation()(f"{self.cat}/{self.name}",
+                                           **self.args)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
         self._tls.depth -= 1
         self._tr._record(self.name, self.cat, self._t0, t1, self.args)
         return False

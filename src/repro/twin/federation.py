@@ -66,7 +66,6 @@ import os
 import threading
 import time
 import traceback
-from collections import deque
 from dataclasses import dataclass
 
 import jax
@@ -80,7 +79,7 @@ from repro.twin.recovery import TelemetryJournal, TwinCheckpointer, \
     ChaosInjector
 from repro.twin.scenario import ScenarioRefused, ScenarioResult
 from repro.twin.scheduler import SlotFederation
-from repro.twin.server import _HISTORY, TwinServer, TwinServerConfig
+from repro.twin.server import TwinServer, TwinServerConfig
 from repro.twin.sharded import ShardedTickReport
 from repro.twin.service import FleetTopologyConfig
 from repro.twin import wire as W
@@ -214,7 +213,8 @@ def _worker_main(conn, scfg: TwinServerConfig, shard: int, recovery,
                 srv.inject_delay_s = msg.inject_delay_s
                 rep = srv.tick()
                 if ckpt is not None and ckpt.maybe_save(
-                        shard, srv.tick_count, srv.snapshot_state):
+                        shard, srv.tick_count, srv.snapshot_state,
+                        span=srv.tracer.span):
                     last_saved = srv.tick_count
                 conn.send_bytes(W.encode(W.TickDone(
                     tick=int(srv.tick_count),
@@ -430,8 +430,6 @@ class FederationCoordinator:
         self._placement: dict[int, int] = {}
         self._dead: dict[int, int] = {}       # shard -> tick it died on
         self.tick_count = 0
-        self.latencies: deque = deque(maxlen=_HISTORY)
-        self.refresh_counts: deque = deque(maxlen=_HISTORY)
         self.deadline_s = (cfg.deadline_s if cfg.deadline_s is not None
                            else min(s.deadline_s for s in cfg.servers))
 
@@ -689,13 +687,11 @@ class FederationCoordinator:
                 with self.tracer.span("rebalance"):
                     self._rebalance()
             latency = time.perf_counter() - t0
-        self.latencies.append(latency)
         self._m_tick.observe(latency)
         if latency > self.deadline_s:
             self._m_violations.inc()
         live = [r for r in reports if r is not None]
         n_active = sum(r.n_active for r in live)
-        self.refresh_counts.append(n_active)
         if n_active:
             self._m_refreshes.inc(n_active)
         self._m_dead.set(len(self._dead))
@@ -819,8 +815,6 @@ class FederationCoordinator:
         return out
 
     def reset_latency_stats(self) -> None:
-        self.latencies.clear()
-        self.refresh_counts.clear()
         self._m_tick.reset()
         self._m_violations.reset()
         self._m_refreshes.reset()

@@ -35,6 +35,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import null_span
+
 __all__ = ["PackedFleet", "fleet_scores", "fleet_pressure"]
 
 
@@ -210,16 +212,24 @@ def _device_operands(fleet: PackedFleet):
 
 
 def fleet_scores(fleet: PackedFleet, *, min_samples: int, sw: float,
-                 dw: float, k: int):
+                 dw: float, k: int, span=null_span):
     """Host wrapper: returns (cand_rows, cand_prio, n_waiting, pressure)
     as numpy/python values.  Rows whose cand_prio is -inf are padding
-    (fewer than k twins waiting) — callers must drop them."""
+    (fewer than k twins waiting) — callers must drop them.  Each of the
+    four reads back is a `sync` span of `span` (a `Tracer.span`)."""
     k = max(1, min(k, fleet.capacity))
     cand_rows, cand_prio, n_waiting, pressure = _fleet_scores(
         *_device_operands(fleet), np.int32(min_samples), np.float32(sw),
         np.float32(dw), k)
-    return (np.asarray(cand_rows), np.asarray(cand_prio),
-            int(n_waiting), float(pressure))
+    with span("sync", site="plan.rows"):
+        cand_rows = np.asarray(cand_rows)
+    with span("sync", site="plan.prio"):
+        cand_prio = np.asarray(cand_prio)
+    with span("sync", site="plan.waiting"):
+        n_waiting = int(n_waiting)
+    with span("sync", site="plan.pressure"):
+        pressure = float(pressure)
+    return cand_rows, cand_prio, n_waiting, pressure
 
 
 @jax.jit
@@ -234,9 +244,11 @@ def _fleet_pressure(samples, at_deploy, deployed, divergence, resident,
 
 
 def fleet_pressure(fleet: PackedFleet, *, min_samples: int, sw: float,
-                   dw: float) -> float:
+                   dw: float, span=null_span) -> float:
     """Aggregate refit demand as one fused device reduction — the number
     `SlotFederation.rebalance` consumes, without an O(twins) host scan."""
-    return float(_fleet_pressure(
+    pressure = _fleet_pressure(
         *_device_operands(fleet), np.int32(min_samples), np.float32(sw),
-        np.float32(dw)))
+        np.float32(dw))
+    with span("sync", site="rebalance.pressure"):
+        return float(pressure)

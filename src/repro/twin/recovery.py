@@ -53,7 +53,7 @@ import numpy as np
 from repro.distributed.fault_tolerance import (FailureInjector,
                                                SimulatedPreemption,
                                                StragglerDetector)
-from repro.obs import MetricRegistry
+from repro.obs import MetricRegistry, null_span
 from repro.train import checkpoint
 
 __all__ = ["RecoveryConfig", "TwinCheckpointer", "TelemetryJournal",
@@ -146,19 +146,22 @@ class TwinCheckpointer:
 
     # ------------------------------------------------------------------ #
     def maybe_save(self, shard: int, tick: int, snapshot_fn,
-                   force: bool = False) -> bool:
+                   force: bool = False, span=null_span) -> bool:
         """Checkpoint shard `shard` if its tick hits the cadence.
 
         `snapshot_fn()` must return a host pytree of numpy arrays that the
         background writer may read without racing the serving thread (i.e.
-        copies — `TwinServer.snapshot_state`)."""
+        copies — `TwinServer.snapshot_state`).  The read back of its device
+        leaves is a `sync` span of `span` (a `Tracer.span`)."""
         if not force and (tick % self.cfg.ckpt_every != 0 or tick == 0):
             return False
         prev = self._pending.get(shard)
         if prev is not None:
             prev.join()
         t0 = time.perf_counter()
-        host_tree = jax.tree.map(np.asarray, jax.device_get(snapshot_fn()))
+        snapshot = snapshot_fn()
+        with span("sync", site="checkpoint.snapshot"):
+            host_tree = jax.tree.map(np.asarray, jax.device_get(snapshot))
         self._m_snapshot.observe(time.perf_counter() - t0)
         d = self.shard_dir(shard)
 

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.rk4.ops import rk4_poly_solve
+from repro.obs import null_span
 
 __all__ = [
     "ScenarioConfig", "ScenarioRefused", "ScenarioResult", "ScenarioRunner",
@@ -124,11 +125,14 @@ class ScenarioRunner:
 
     One runner per model configuration (library + dt + backend); shards
     with identical configs share a runner — and therefore a jit cache —
-    via `share_modules_from`, exactly like the fleet model itself.
+    via `share_modules_from`, exactly like the fleet model itself.  `span`
+    is the building server's `Tracer.span` (shards share one tracer).
     """
 
     def __init__(self, library, dt: float, cfg: ScenarioConfig, *,
-                 use_pallas: bool = False, interpret: bool | None = None):
+                 use_pallas: bool = False, interpret: bool | None = None,
+                 span=null_span):
+        self.span = span
         self.lib = library
         self.dt = float(dt)
         self.cfg = cfg
@@ -169,12 +173,16 @@ class ScenarioRunner:
     # ------------------------------------------------------------------ #
     def rollout(self, theta_hist, count: int, y0, us) -> tuple:
         """Device entry point; shapes as `_roll_impl`. Blocks on the result
-        (host arrays out — scenario answers leave the device anyway)."""
+        (host arrays out — scenario answers leave the device anyway); each
+        array's read back is a `sync` span."""
         us = jnp.asarray(us, jnp.float32)
         if us.ndim != 3:
             raise ValueError(f"us must be [K, H, m], got {us.shape}")
-        center, lo, hi, conf = self._roll(
+        out = self._roll(
             jnp.asarray(theta_hist), jnp.int32(count),
             jnp.asarray(y0, jnp.float32), us)
-        return (np.asarray(center), np.asarray(lo), np.asarray(hi),
-                np.asarray(conf))
+        host = []
+        for x in out:
+            with self.span("sync", site="scenario.result"):
+                host.append(np.asarray(x))
+        return tuple(host)

@@ -59,6 +59,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro.obs import null_span
 from repro.twin.packed import PackedFleet, fleet_pressure, fleet_scores
 
 __all__ = ["TwinRecord", "SchedulerConfig", "SchedulePlan", "SchedulerMetrics",
@@ -419,9 +420,12 @@ class PackedRefitScheduler:
 
     def __init__(self, cfg: SchedulerConfig,
                  metrics: SchedulerMetrics | None = None, *,
-                 quantum: float = 0.25):
+                 quantum: float = 0.25, span=null_span):
+        """`span` is the server's `Tracer.span`: each read back of the
+        fused scoring call is a `sync` span."""
         self.cfg = cfg
         self.metrics = metrics
+        self.span = span
         self.queue = PriorityBuckets(quantum)
         self.last_pressure = 0.0
         self.last_waiting = 0
@@ -445,7 +449,7 @@ class PackedRefitScheduler:
         cfg = self.cfg
         p = fleet_pressure(fleet, min_samples=cfg.min_samples,
                            sw=cfg.staleness_weight,
-                           dw=cfg.divergence_weight)
+                           dw=cfg.divergence_weight, span=self.span)
         self.last_pressure = p
         if self.metrics is not None:
             self.metrics.pressure.set(p)
@@ -483,7 +487,7 @@ class PackedRefitScheduler:
         # ONE device pass: top-k waiting candidates + queue depth + pressure
         cand_rows, cand_prio32, n_waiting, pressure = fleet_scores(
             fleet, min_samples=cfg.min_samples, sw=cfg.staleness_weight,
-            dw=cfg.divergence_weight, k=cfg.slots)
+            dw=cfg.divergence_weight, k=cfg.slots, span=self.span)
         self.last_pressure = pressure
         self.last_waiting = n_waiting
         keep = np.isfinite(cand_prio32)

@@ -37,7 +37,10 @@ is tracked separately (`stage_summary`) — the scale benchmark's evidence that
 guard cost stays flat as the tracked fleet grows.  All serving stats flow
 through a bounded `repro.obs` metrics registry (scrape via
 `server.metrics.expose()`; catalog in docs/OBSERVABILITY.md), and an optional
-`Tracer` wraps every stage in spans exportable as a Perfetto-loadable trace.
+`Tracer` wraps every stage and its parts in spans exportable as a
+Perfetto-loadable trace.  Every place where the serving thread waits on the
+device (a read-back or the tick's final block) sits in a `sync` span whose
+`site` names it, apart from the dispatch before it.
 The paper's mission budget: beat the 5 s human-pilot reaction time 5x —
 refresh every deployed twin in <= 1 s.
 
@@ -48,7 +51,6 @@ from __future__ import annotations
 
 import threading
 import time
-from collections import deque
 from dataclasses import dataclass, field
 
 import jax
@@ -77,13 +79,6 @@ from repro.twin.stream import (FlushBatch, RingConfig, StagingBuffer,
 __all__ = ["TwinServerConfig", "TickReport", "TwinServer"]
 
 _STAGES = ("flush", "guard", "schedule", "refit")
-
-# recent-tick window kept for debugging/back-compat (`srv.latencies` et al.).
-# Authoritative latency stats come from the bounded metrics-registry
-# histograms; these deques exist so short interactive runs can still inspect
-# raw per-tick numbers without the registry — and, unlike the seed's bare
-# lists, they cannot grow without bound in a long-running service.
-_HISTORY = 4096
 
 
 @dataclass(frozen=True)
@@ -207,7 +202,8 @@ class TwinServer:
                                          interpret=m.interpret)
             self.scenario_runner = ScenarioRunner(
                 self.fleet.model.lib, m.dt, cfg.scenario,
-                use_pallas=m.use_pallas, interpret=m.interpret)
+                use_pallas=m.use_pallas, interpret=m.interpret,
+                span=self.tracer.span)
         self._rstate = self.ring.init()
         self._key = jax.random.PRNGKey(cfg.seed if seed is None else seed)
         self._fstate = self.fleet.init(self._split())
@@ -222,7 +218,8 @@ class TwinServer:
         sched_metrics = SchedulerMetrics.create(self.metrics, self._labels)
         if cfg.scheduler == "bucketed":
             self.scheduler = PackedRefitScheduler(sched_cfg,
-                                                  metrics=sched_metrics)
+                                                  metrics=sched_metrics,
+                                                  span=self.tracer.span)
         elif cfg.scheduler == "reference":
             self.scheduler = RefitScheduler(sched_cfg, metrics=sched_metrics)
         else:
@@ -282,12 +279,6 @@ class TwinServer:
         self.inject_delay_s = 0.0     # chaos straggler (twin/recovery.py):
                                       # slept INSIDE the timed tick region so
                                       # the degradation policy sees the stall
-        # recent-tick raw numbers (bounded; registry histograms are the
-        # authoritative, never-growing stats — see _HISTORY note above)
-        self.latencies: deque[float] = deque(maxlen=_HISTORY)
-        self.stage_times: dict[str, deque] = {s: deque(maxlen=_HISTORY)
-                                              for s in _STAGES}
-        self.refresh_counts: deque[int] = deque(maxlen=_HISTORY)
         self.events: list[GuardEvent] = []
         self._init_instruments()
 
@@ -518,19 +509,21 @@ class TwinServer:
         return int(self._m_dropped.value)
 
     def _apply(self, batch: FlushBatch) -> int:
-        if batch.dropped:
-            self._m_dropped.inc(batch.dropped)
-            self._m_overflow.inc()
-        for row, raw in batch.received.items():
-            rec = self._row2rec[row]
-            rec.samples += raw
-            self.packed.samples[row] = rec.samples
-            if rec.deployed and rec.samples >= self._guard_min:
-                self._guard_add(rec)
-        self._rstate = self.ring.ingest(
-            self._rstate, jnp.asarray(batch.slots), jnp.asarray(batch.ys),
-            jnp.asarray(batch.us), jnp.asarray(batch.counts))
-        return sum(batch.received.values())
+        """Host accounting, then the ring scatter, under an `apply` span."""
+        with self.tracer.span("apply", cat="ingest", **self._labels):
+            if batch.dropped:
+                self._m_dropped.inc(batch.dropped)
+                self._m_overflow.inc()
+            for row, raw in batch.received.items():
+                rec = self._row2rec[row]
+                rec.samples += raw
+                self.packed.samples[row] = rec.samples
+                if rec.deployed and rec.samples >= self._guard_min:
+                    self._guard_add(rec)
+            self._rstate = self.ring.ingest(
+                self._rstate, jnp.asarray(batch.slots), jnp.asarray(batch.ys),
+                jnp.asarray(batch.us), jnp.asarray(batch.counts))
+            return sum(batch.received.values())
 
     def _flush(self) -> int:
         if self._pump is not None:
@@ -664,7 +657,9 @@ class TwinServer:
                 return [], 0
             rows = jnp.arange(self.cfg.max_twins)
             ys, us = self.ring.latest(self._rstate, rows, gw)
-            scores = np.asarray(self.guard.score(self._theta[:-1], ys, us))
+            scores = self.guard.score(self._theta[:-1], ys, us)
+            with self.tracer.span("sync", site="guard.scores"):
+                scores = np.asarray(scores)
             recs = list(live.values())
             srows = np.fromiter((r.ring_slot for r in recs), np.int64,
                                 count=len(recs))
@@ -692,7 +687,9 @@ class TwinServer:
             rows_np[:len(pick)] = pick
             rows = jnp.asarray(rows_np)
             ys, us = self.ring.latest(self._rstate, rows, gw)
-            scores = np.asarray(self.guard.score(self._theta[rows], ys, us))
+            scores = self.guard.score(self._theta[rows], ys, us)
+            with self.tracer.span("sync", site="guard.scores"):
+                scores = np.asarray(scores)
             recs = [live[int(row)] for row in pick]
             srows = np.asarray(pick, np.int64)
             raw = scores[:len(recs)]
@@ -765,16 +762,20 @@ class TwinServer:
                     if self.twins[tid].steps_in_slot >= self.cfg.deploy_after]
                 if deployable:
                     y_win, u_win = self._slot_windows()
-                    self._promote(deployable, y_win, u_win)
+                    with self.tracer.span("promote"):
+                        self._promote(deployable, y_win, u_win)
             return None
         y_win, u_win = self._slot_windows()
-        loss_vec = None
-        for _ in range(self.cfg.steps_per_tick):
-            self._fstate, loss_vec, _ = self.fleet.train_step_per_slot(
-                self._fstate, y_win, u_win)
-        # report loss over ASSIGNED slots only — scratch-parked slots train
-        # on zero windows and would dilute the mean toward zero
-        loss = float(np.mean(np.asarray(loss_vec)[sorted(self._slot_twin)]))
+        with self.tracer.span("train"):
+            loss_vec = None
+            for _ in range(self.cfg.steps_per_tick):
+                self._fstate, loss_vec, _ = self.fleet.train_step_per_slot(
+                    self._fstate, y_win, u_win)
+            with self.tracer.span("sync", site="refit.loss"):
+                loss_vec = np.asarray(loss_vec)
+            # report loss over ASSIGNED slots only — scratch-parked slots
+            # train on zero windows and would dilute the mean toward zero
+            loss = float(np.mean(loss_vec[sorted(self._slot_twin)]))
         deployable = []
         for slot, tid in self._slot_twin.items():
             rec = self.twins[tid]
@@ -784,7 +785,8 @@ class TwinServer:
             if rec.steps_in_slot >= self.cfg.deploy_after:
                 deployable.append(slot)
         if deployable and not skip_promote:
-            self._promote(deployable, y_win, u_win)
+            with self.tracer.span("promote"):
+                self._promote(deployable, y_win, u_win)
         return loss
 
     def _promote(self, deployable, y_win, u_win) -> None:
@@ -802,8 +804,12 @@ class TwinServer:
         thetas = self.fleet.recover_all(self._fstate, y_win, u_win)
         ys_g, us_g = self.ring.latest(self._rstate, rows,
                                       self.cfg.guard.window)
-        cand = np.asarray(self.guard.score(thetas, ys_g, us_g))
-        inc = np.asarray(self.guard.score(self._theta[rows], ys_g, us_g))
+        cand = self.guard.score(thetas, ys_g, us_g)
+        with self.tracer.span("sync", site="promote.cand"):
+            cand = np.asarray(cand)
+        inc = self.guard.score(self._theta[rows], ys_g, us_g)
+        with self.tracer.span("sync", site="promote.inc"):
+            inc = np.asarray(inc)
         targets = np.full((self.cfg.refit_slots,), self._scratch,
                           dtype=np.int32)
         promoted = set()
@@ -886,13 +892,17 @@ class TwinServer:
             # threads may register new twins mid-tick and dict iteration
             # must not race those inserts.
             with span("schedule"):
-                if isinstance(self.scheduler, PackedRefitScheduler):
-                    plan = self.scheduler.plan(self.packed, self._slot_ring,
-                                               max_active=self._max_active)
-                else:
-                    plan = self.scheduler.plan(self.twin_snapshot(),
-                                               max_active=self._max_active)
-                self._apply_plan(plan)
+                with span("plan"):
+                    if isinstance(self.scheduler, PackedRefitScheduler):
+                        plan = self.scheduler.plan(
+                            self.packed, self._slot_ring,
+                            max_active=self._max_active)
+                    else:
+                        plan = self.scheduler.plan(
+                            self.twin_snapshot(),
+                            max_active=self._max_active)
+                with span("admit", admitted=len(plan.admit)):
+                    self._apply_plan(plan)
             t3 = time.perf_counter()
             with span("refit"):
                 if defer_refit:
@@ -901,13 +911,12 @@ class TwinServer:
                     self._m_shed["promote"].inc()
                 loss = self._refit(defer=defer_refit,
                                    skip_promote=skip_promote)
-                jax.block_until_ready(self._theta)
+                with span("sync", site="tick.block"):
+                    jax.block_until_ready(self._theta)
             t4 = time.perf_counter()
         latency = t4 - t0
-        self.latencies.append(latency)
         self._m_tick.observe(latency)
         for stage, dt in zip(_STAGES, (t1 - t0, t2 - t1, t3 - t2, t4 - t3)):
-            self.stage_times[stage].append(dt)
             self._m_stage[stage].observe(dt)
         if latency > self.cfg.deadline_s:
             self._m_violations.inc()
@@ -917,7 +926,6 @@ class TwinServer:
             self._m_deg_trans[
                 "up" if deg_ev.to_level > deg_ev.from_level else "down"].inc()
         n_active = len(self._slot_twin)
-        self.refresh_counts.append(n_active)
         if n_active:
             self._m_refreshes.inc(n_active)
         self._m_tracked.set(len(self.twins))
@@ -1012,12 +1020,15 @@ class TwinServer:
                 self._m_scn_shrunk.inc()
             us_eff = (np.zeros((eff, horizon, m), np.float32)
                       if us is None else np.ascontiguousarray(us[:eff]))
-            ys, _ = self.ring.latest(self._rstate,
-                                     jnp.asarray([rec.ring_slot]), 0)
-            center, lo, hi, conf = self.scenario_runner.rollout(
-                self._theta_hist[rec.ring_slot],
-                int(self._hist_count[rec.ring_slot]),
-                ys[0, -1, :], us_eff)
+            with self.tracer.span("gather"):
+                ys, _ = self.ring.latest(self._rstate,
+                                         jnp.asarray([rec.ring_slot]), 0)
+                theta_hist = self._theta_hist[rec.ring_slot]
+                count = int(self._hist_count[rec.ring_slot])
+                y0 = ys[0, -1, :]
+            with self.tracer.span("rollout"):
+                center, lo, hi, conf = self.scenario_runner.rollout(
+                    theta_hist, count, y0, us_eff)
             self._m_scn_requests.inc()
             self._m_scn_rollouts.inc(eff * scfg.ensemble)
             for c in conf:
@@ -1034,10 +1045,6 @@ class TwinServer:
         warmup).  Resets the tick/stage histograms and the violation/refresh
         counters; LEAVES the monotone accounting counters (dropped samples,
         overflows, guard events) alone — those are lifetime totals."""
-        self.latencies.clear()
-        self.refresh_counts.clear()
-        for times in self.stage_times.values():
-            times.clear()
         self._m_tick.reset()
         for h in self._m_stage.values():
             h.reset()

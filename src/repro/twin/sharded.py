@@ -35,7 +35,6 @@ objects — `benchmarks/online_scale.py` is the scaling evidence.
 from __future__ import annotations
 
 import time
-from collections import deque
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -45,8 +44,7 @@ from repro.twin.monitor import GuardEvent
 from repro.twin.recovery import (ChaosInjector, ShardFailure,
                                  TelemetryJournal, TwinCheckpointer)
 from repro.twin.scheduler import SlotFederation
-from repro.twin.server import _HISTORY, TickReport, TwinServer, \
-    TwinServerConfig
+from repro.twin.server import TickReport, TwinServer, TwinServerConfig
 from repro.twin.service import FleetTopologyConfig
 
 __all__ = ["ShardedTwinConfig", "ShardedTickReport", "ShardedTwinServer"]
@@ -135,8 +133,6 @@ class ShardedTwinServer:
 
         self._placement: dict[int, int] = {}      # twin_id -> shard index
         self.tick_count = 0
-        self.latencies: deque = deque(maxlen=_HISTORY)
-        self.refresh_counts: deque = deque(maxlen=_HISTORY)
         self.deadline_s = (cfg.deadline_s if cfg.deadline_s is not None
                            else min(s.cfg.deadline_s for s in self.shards))
 
@@ -343,18 +339,17 @@ class ShardedTwinServer:
                 reports.append(srv.tick())
                 if self.checkpointer is not None:
                     self.checkpointer.maybe_save(i, srv.tick_count,
-                                                 srv.snapshot_state)
+                                                 srv.snapshot_state,
+                                                 span=self.tracer.span)
             if restarted or self.tick_count % self.cfg.rebalance_every == 0:
                 with self.tracer.span("rebalance"):
                     self._rebalance()
             latency = time.perf_counter() - t0
-        self.latencies.append(latency)
         self._m_tick.observe(latency)
         if latency > self.deadline_s:
             self._m_violations.inc()
         live = [r for r in reports if r is not None]
         n_active = sum(r.n_active for r in live)
-        self.refresh_counts.append(n_active)
         if n_active:
             self._m_refreshes.inc(n_active)
         self._m_dead.set(len(self._dead))
@@ -453,8 +448,6 @@ class ShardedTwinServer:
 
     # ------------------------------------------------------------------ #
     def reset_latency_stats(self) -> None:
-        self.latencies.clear()
-        self.refresh_counts.clear()
         self._m_tick.reset()
         self._m_violations.reset()
         self._m_refreshes.reset()

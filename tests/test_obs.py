@@ -1,7 +1,10 @@
 """Observability layer: registry accuracy, thread-safety, trace format,
 exporters, the bench-regression gate, and serving-loop non-interference."""
+import glob
 import importlib.util
 import json
+import os
+import subprocess
 import sys
 import threading
 from pathlib import Path
@@ -208,6 +211,86 @@ def test_tracer_disabled_is_noop():
     assert len(tr) == 0
 
 
+def _profiler_host_events(trace_dir) -> list[tuple]:
+    """(name, start_ns, end_ns, stats) of every host-plane event."""
+    from jax.profiler import ProfileData
+    paths = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))
+    assert paths, "the profiler wrote no trace"
+    out = []
+    for plane in ProfileData.from_file(paths[-1]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                {k: str(v) for k, v in ev.stats}))
+    return out
+
+
+def test_spans_mirrored_into_profiler_trace(tmp_path):
+    """Recorded spans land on the profiler's host plane as `<cat>/<name>`
+    with their args, nested as recorded; disabled and unsampled spans write
+    nothing there."""
+    on, off = Tracer(), Tracer(enabled=False)
+    sampled = Tracer(sample_every=2)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with on.span("tick", tick=7, shard="3"):
+            with on.span("guard"):
+                with on.span("sync", site="guard.scores"):
+                    pass
+            with on.span("pump_flush", cat="ingest"):
+                pass
+        with off.span("disabled_root"):
+            pass
+        for i in range(2):
+            with sampled.span(f"sampled_{i}"):
+                with sampled.span(f"child_{i}"):
+                    pass
+    finally:
+        jax.profiler.stop_trace()
+    events = {name: (s, e, stats)
+              for name, s, e, stats in _profiler_host_events(tmp_path)}
+    tick, guard, sync, pump = (events[n] for n in (
+        "twin/tick", "twin/guard", "twin/sync", "ingest/pump_flush"))
+    assert tick[2]["tick"] == "7" and tick[2]["shard"] == "3"
+    assert sync[2]["site"] == "guard.scores"
+    # nested as recorded
+    assert tick[0] <= guard[0] <= sync[0] <= sync[1] <= guard[1] <= tick[1]
+    assert guard[1] <= pump[0] <= pump[1] <= tick[1]
+    assert "twin/sampled_0" in events and "twin/child_0" in events
+    names = " ".join(events)
+    for absent in ("disabled_root", "sampled_1", "child_1"):
+        assert absent not in names
+    # the Chrome export is unchanged: plain names, args as recorded
+    xs = {e["name"]: e for e in on.to_chrome_trace()["traceEvents"]
+          if e["ph"] == "X"}
+    assert set(xs) == {"tick", "guard", "sync", "pump_flush"}
+    assert xs["sync"]["args"] == {"site": "guard.scores"}
+
+
+def test_obs_imports_and_records_without_jax():
+    """`repro.obs` stays importable, and its tracer usable, where JAX
+    cannot be imported."""
+    code = "\n".join([
+        "import sys",
+        "sys.modules['jax'] = None            # `import jax` now fails",
+        "import repro.obs as obs",
+        "tr = obs.Tracer()",
+        "with tr.span('tick', tick=1):",
+        "    with tr.span('sync', site='x'):",
+        "        pass",
+        "assert len(tr) == 2",
+        "assert obs.Tracer(enabled=False).span('x') is obs.NULL_SPAN",
+    ])
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = {**os.environ, "PYTHONPATH": src}
+    subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                   timeout=120)
+
+
 # --------------------------------------------------------------------- #
 # exporters
 # --------------------------------------------------------------------- #
@@ -267,7 +350,7 @@ def test_check_bench_skips_new_configs_and_non_numeric():
 # --------------------------------------------------------------------- #
 # non-interference: tracing must not change serving behaviour
 # --------------------------------------------------------------------- #
-def _run_server(ys, us, dt, tracer):
+def _run_server(ys, us, dt, tracer, query=False):
     cfg = TwinServerConfig(
         merinda=MerindaConfig(n=2, m=0, order=2, hidden=8, head_hidden=8,
                               n_active=4, dt=dt),
@@ -276,6 +359,8 @@ def _run_server(ys, us, dt, tracer):
         min_residency=2, max_residency=6, guard=GuardConfig(window=16),
         seed=0)
     srv = TwinServer(cfg, tracer=tracer)
+    # a zero model serves every twin, so the guard scores from the start
+    srv.deploy_many(range(64), np.zeros((2, srv.fleet.model.lib.size)))
     chunk = 10
     reports = []
     for t in range(8):
@@ -283,7 +368,44 @@ def _run_server(ys, us, dt, tracer):
             srv.ingest(i, ys[i, t * chunk:(t + 1) * chunk],
                        us[i, t * chunk:(t + 1) * chunk])
         reports.append(srv.tick())
-    return reports
+    answer = srv.scenario(0, 6, np.zeros((2, 6, 0)), k=2) if query else None
+    return reports, answer
+
+
+def _span_paths(events) -> list[tuple[str, ...]]:
+    """Each recorded span as its chain of names from the root, in start
+    order (`sync` spans as `sync:<site>`); nesting is containment on one
+    thread."""
+    xs = sorted((e for e in events if e["ph"] == "X"),
+                key=lambda e: (e["tid"], e["ts"], -e["dur"]))
+    out, stack = [], []
+    for e in xs:
+        name = (f"sync:{e['args']['site']}" if e["name"] == "sync"
+                else e["name"])
+        while stack and not (stack[-1][0]["tid"] == e["tid"]
+                             and e["ts"] + e["dur"]
+                             <= stack[-1][0]["ts"] + stack[-1][0]["dur"]
+                             + 1e-3):
+            stack.pop()
+        path = (stack[-1][1] if stack else ()) + (name,)
+        out.append(path)
+        stack.append((e, path))
+    return out
+
+
+# every place where a promoting tick waits on the device, in order, with the
+# stage span that holds it
+_TICK_SYNCS = [
+    ("tick", "guard", "sync:guard.scores"),
+    ("tick", "schedule", "plan", "sync:plan.rows"),
+    ("tick", "schedule", "plan", "sync:plan.prio"),
+    ("tick", "schedule", "plan", "sync:plan.waiting"),
+    ("tick", "schedule", "plan", "sync:plan.pressure"),
+    ("tick", "refit", "train", "sync:refit.loss"),
+    ("tick", "refit", "promote", "sync:promote.cand"),
+    ("tick", "refit", "promote", "sync:promote.inc"),
+    ("tick", "refit", "sync:tick.block"),
+]
 
 
 def test_tracing_on_off_identical_tick_reports():
@@ -295,9 +417,10 @@ def test_tracing_on_off_identical_tick_reports():
                         noise_std=0.002)
     ys, us = np.asarray(tr.ys_noisy), np.asarray(tr.us)
 
-    off = _run_server(ys, us, sys_.spec.dt, Tracer(enabled=False))
+    off, off_answer = _run_server(ys, us, sys_.spec.dt,
+                                  Tracer(enabled=False), query=True)
     tracer = Tracer(sample_every=1)
-    on = _run_server(ys, us, sys_.spec.dt, tracer)
+    on, on_answer = _run_server(ys, us, sys_.spec.dt, tracer, query=True)
 
     assert len(tracer) > 0                        # spans actually recorded
     for a, b in zip(off, on):
@@ -314,6 +437,28 @@ def test_tracing_on_off_identical_tick_reports():
             assert b.loss is None
         else:
             assert a.loss == pytest.approx(b.loss, rel=1e-6)
+    np.testing.assert_array_equal(off_answer.ys, on_answer.ys)
+    np.testing.assert_array_equal(off_answer.confidence, on_answer.confidence)
     names = {e["name"] for e in tracer.to_chrome_trace()["traceEvents"]
              if e["ph"] == "X"}
     assert {"tick", "flush", "guard", "schedule", "refit"} <= names
+
+    # each traced tick's waits on the device, in order, each in its stage
+    paths = _span_paths(tracer.to_chrome_trace()["traceEvents"])
+    ticks, sites = [], None
+    for path in paths:
+        if path == ("tick",):
+            sites = []
+            ticks.append(sites)
+        elif path[0] == "tick" and path[-1].startswith("sync:"):
+            sites.append(path)
+    assert len(ticks) == len(on)
+    assert _TICK_SYNCS in ticks                  # a promoting tick
+    assert all(t[-1] == ("tick", "refit", "sync:tick.block") for t in ticks)
+    assert ("tick", "flush", "apply") in paths
+    assert ("tick", "schedule", "admit") in paths
+    # the query's four read backs sit in its rollout, after the gather
+    query = [p for p in paths if p[0] == "scenario"]
+    assert query == [("scenario",), ("scenario", "gather"),
+                     ("scenario", "rollout")] + \
+        [("scenario", "rollout", "sync:scenario.result")] * 4

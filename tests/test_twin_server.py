@@ -153,7 +153,7 @@ def test_server_admits_and_refits(lv_world):
     # refit losses are finite once slots are active
     assert all(np.isfinite(r.loss) for r in reports if r.loss is not None)
     # every tick's latency was recorded
-    assert len(srv.latencies) == 12
+    assert srv.latency_summary()["ticks"] == 12
     # per-slot step counters advanced (incremental stepping)
     assert int(srv._fstate["steps"].max()) > 0
 

@@ -194,7 +194,7 @@ def test_sharded_routes_and_serves(lv_world):
         # placement is modulo and sticky
         assert srv.shard_of(4) == 0 and srv.shard_of(5) == 1
         assert sorted(srv.shards[0].twins) == [0, 2, 4]
-        assert len(srv.latencies) == 8
+        assert srv.latency_summary()["ticks"] == 8
     finally:
         srv.close()
 
