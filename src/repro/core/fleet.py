@@ -10,9 +10,11 @@ separate data per twin) and exposes:
   * fleet_init / fleet_step  — one fused training step for every twin
     (the latency-critical fused step; examples/fleet_twinning.py),
   * recover_all              — batched model extraction,
-  * reset_slot               — re-initialize ONE fleet slot in place (the
-    online-serving admission path: twin/scheduler.py admits a newly-tracked
-    object into a refit slot without touching the other twins).
+  * reset_slot               — re-initialize ONE fleet slot in place,
+    without touching the other twins,
+  * reset_slots              — the online-serving admission path: every
+    slot twin/scheduler.py admits in one tick, reset in one fixed-shape
+    program (sequential `reset_slot`s with sequential key splits).
 
 Online serving (twin/server.py) treats the fleet axis as a bounded pool of
 REFIT SLOTS: twins are admitted/evicted dynamically, so per-slot training
@@ -139,6 +141,26 @@ class FleetMerinda:
             nu=jax.tree.map(lambda a: a.at[slot].set(0.0), opt.nu))
         return {"params": params, "opt": opt, "step": state["step"],
                 "steps": state["steps"].at[slot].set(0)}
+
+    @partial(jax.jit, static_argnames=("self",))
+    def reset_slots(self, state, slots, key, y_win, u_win):
+        """`reset_slot` for every admission of a tick, in one program.
+
+        slots: [R] int32, the admitted slots in admission order, padded
+        with -1 after the last one; y_win [R, N, k+1, n] and u_win
+        [R, N, k, m] are the admitted twins' windows (rows past the last
+        admission are ignored).  Admission i draws `key, sub = split(key)`
+        and resets `slots[i]` from `sub` and its windows, exactly as a host
+        loop of `reset_slot` calls would, so the fixed shape compiles once
+        and the loop runs only as many times as there are admissions.
+        Returns (state, key) with the key advanced past every draw.
+        """
+        def admit(i, carry):
+            state, key = carry
+            key, sub = jax.random.split(key)
+            return (self.reset_slot(state, slots[i], sub, y_win[i],
+                                    u_win[i]), key)
+        return jax.lax.fori_loop(0, jnp.sum(slots >= 0), admit, (state, key))
 
     # ------------------------------------------------------------------ #
     @partial(jax.jit, static_argnames=("self",))

@@ -52,6 +52,7 @@ from __future__ import annotations
 import threading
 import time
 from dataclasses import dataclass, field
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -79,6 +80,21 @@ from repro.twin.stream import (FlushBatch, RingConfig, StagingBuffer,
 __all__ = ["TwinServerConfig", "TickReport", "TwinServer"]
 
 _STAGES = ("flush", "guard", "schedule", "refit")
+
+
+@partial(jax.jit, static_argnames=("ring", "fleet", "window", "stride",
+                                   "length"))
+def _admit(ring, fleet, rstate, fstate, admit, key, *, window, stride, length):
+    """One tick's admissions as one device program: gather the admitted
+    twins' windows (row 1 of `admit`: ring rows) and reset their slots
+    (row 0: slots in plan order, -1 past the last) with sequential key
+    splits.  Built from the class-level functions, so a wrapper set on an
+    instance's `windows` or `reset_slots` is not traced into it."""
+    y_win, u_win = TelemetryRing.windows(ring, rstate, admit[1],
+                                         window=window, stride=stride,
+                                         length=length)
+    return FleetMerinda.reset_slots(fleet, fstate, admit[0], key, y_win,
+                                    u_win)
 
 
 @dataclass(frozen=True)
@@ -205,8 +221,9 @@ class TwinServer:
                 use_pallas=m.use_pallas, interpret=m.interpret,
                 span=self.tracer.span)
         self._rstate = self.ring.init()
-        self._key = jax.random.PRNGKey(cfg.seed if seed is None else seed)
-        self._fstate = self.fleet.init(self._split())
+        self._key, key = jax.random.split(
+            jax.random.PRNGKey(cfg.seed if seed is None else seed))
+        self._fstate = self.fleet.init(key)
 
         sched_cfg = SchedulerConfig(
             slots=cfg.refit_slots, min_samples=self.min_samples,
@@ -301,6 +318,14 @@ class TwinServer:
             "twin_slot_refreshes_total",
             help="refit-slot train advances (active slots summed per tick)",
             labels=lab)
+        self._m_admissions = M.counter(
+            "twin_slot_admissions_total",
+            help="twins admitted into refit slots (slots reset)",
+            labels=lab)
+        self._m_admit_calls = M.counter(
+            "twin_admit_calls_total",
+            help="fused admission launches (one per admitting tick)",
+            labels=lab)
         self._m_dropped = M.counter(
             "twin_dropped_samples_total",
             help="telemetry samples truncated by flush backlog (ring would "
@@ -375,11 +400,6 @@ class TwinServer:
             help="per-scenario ensemble confidence (1 = recent thetas "
                  "agree)", bounds=(0.1, 0.25, 0.5, 0.75, 0.9, 0.99, 1.0),
             labels=lab)
-
-    # ------------------------------------------------------------------ #
-    def _split(self):
-        self._key, sub = jax.random.split(self._key)
-        return sub
 
     # ------------------------------------------------------------------ #
     def register(self, twin_id: int) -> TwinRecord:
@@ -730,14 +750,13 @@ class TwinServer:
             rec.residency = rec.steps_in_slot = 0
             packed.resident[rec.ring_slot] = False
             packed.residency[rec.ring_slot] = 0
-        for slot, tid in plan.admit:
+        if not plan.admit:
+            return
+        admit = np.full((2, self.cfg.refit_slots), -1, np.int32)
+        admit[1] = self._scratch
+        for i, (slot, tid) in enumerate(plan.admit):
             rec = self.twins[tid]
-            y_w, u_w = self.ring.windows(
-                self._rstate, jnp.asarray([rec.ring_slot]),
-                window=self.cfg.window, stride=self.cfg.stride,
-                length=self.span)
-            self._fstate = self.fleet.reset_slot(
-                self._fstate, jnp.int32(slot), self._split(), y_w[0], u_w[0])
+            admit[:, i] = slot, rec.ring_slot
             rec.refit_slot = slot
             rec.admitted_tick = self.tick_count
             rec.residency = rec.steps_in_slot = 0
@@ -745,6 +764,12 @@ class TwinServer:
             packed.residency[rec.ring_slot] = 0
             self._slot_ring[slot] = rec.ring_slot
             self._slot_twin[slot] = tid
+        self._fstate, self._key = _admit(
+            self.ring, self.fleet, self._rstate, self._fstate, admit,
+            self._key, window=self.cfg.window, stride=self.cfg.stride,
+            length=self.span)
+        self._m_admissions.inc(len(plan.admit))
+        self._m_admit_calls.inc()
 
     def _refit(self, defer: bool = False, skip_promote: bool = False
                ) -> float | None:
