@@ -138,7 +138,7 @@ class ScenarioRunner:
         self.cfg = cfg
         self.use_pallas = bool(use_pallas)
         self.interpret = interpret
-        self._roll = jax.jit(self._roll_impl)
+        self._roll = jax.jit(self._roll_packed)
 
     # ------------------------------------------------------------------ #
     def _roll_impl(self, theta_hist, count, y0, us):
@@ -170,19 +170,28 @@ class ScenarioRunner:
         confidence = 1.0 / (1.0 + spread)
         return center, lo, hi, confidence
 
+    def _roll_packed(self, theta_hist, count, y0, us):
+        """`_roll_impl`'s four results raveled into one float32 array
+        (center, lo, hi, then confidence), so the host reads them back in
+        one transfer."""
+        return jnp.concatenate(
+            [x.ravel() for x in self._roll_impl(theta_hist, count, y0, us)])
+
     # ------------------------------------------------------------------ #
     def rollout(self, theta_hist, count: int, y0, us) -> tuple:
         """Device entry point; shapes as `_roll_impl`. Blocks on the result
-        (host arrays out — scenario answers leave the device anyway); each
-        array's read back is a `sync` span."""
-        us = jnp.asarray(us, jnp.float32)
+        (host arrays out — scenario answers leave the device anyway): one
+        program, which takes `us` and `count` straight from the host, and
+        one read back in a `sync` span, split into the four arrays."""
+        us = np.asarray(us, np.float32)
         if us.ndim != 3:
             raise ValueError(f"us must be [K, H, m], got {us.shape}")
-        out = self._roll(
-            jnp.asarray(theta_hist), jnp.int32(count),
-            jnp.asarray(y0, jnp.float32), us)
-        host = []
-        for x in out:
-            with self.span("sync", site="scenario.result"):
-                host.append(np.asarray(x))
-        return tuple(host)
+        packed = self._roll(theta_hist, np.int32(count), y0, us)
+        with self.span("sync", site="scenario.result"):
+            packed = np.asarray(packed)
+        K, H = us.shape[:2]
+        shape = (K, H + 1, np.shape(y0)[-1])
+        size = K * (H + 1) * shape[2]
+        center, lo, hi = (packed[i * size:(i + 1) * size].reshape(shape)
+                          for i in range(3))
+        return center, lo, hi, packed[3 * size:]

@@ -97,6 +97,16 @@ def _admit(ring, fleet, rstate, fstate, admit, key, *, window, stride, length):
                                     u_win)
 
 
+@partial(jax.jit, static_argnames=("ring",))
+def _scenario_gather(ring, rstate, theta_hist, row):
+    """A what-if query's device inputs as one program: the twin's recent
+    thetas [E, n, L] and its newest observed state y0 [n].  `row` (the ring
+    row) is a traced scalar, so one compile serves every twin; the stores
+    come in as arguments, since ingest, deploys and promotion rebind them."""
+    ys, _ = TelemetryRing.latest(ring, rstate, row[None], 0)
+    return theta_hist[row], ys[0, -1]
+
+
 @dataclass(frozen=True)
 class TwinServerConfig(DeadlineConfig):
     """Single-server knobs; `deadline_s` (1.0 s default — 5x under the 5 s
@@ -1046,11 +1056,10 @@ class TwinServer:
             us_eff = (np.zeros((eff, horizon, m), np.float32)
                       if us is None else np.ascontiguousarray(us[:eff]))
             with self.tracer.span("gather"):
-                ys, _ = self.ring.latest(self._rstate,
-                                         jnp.asarray([rec.ring_slot]), 0)
-                theta_hist = self._theta_hist[rec.ring_slot]
+                theta_hist, y0 = _scenario_gather(
+                    self.ring, self._rstate, self._theta_hist,
+                    np.int32(rec.ring_slot))
                 count = int(self._hist_count[rec.ring_slot])
-                y0 = ys[0, -1, :]
             with self.tracer.span("rollout"):
                 center, lo, hi, conf = self.scenario_runner.rollout(
                     theta_hist, count, y0, us_eff)

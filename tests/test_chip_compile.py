@@ -130,14 +130,15 @@ def test_rk4_fleet_grad_compiles(one_chip):
 
 
 def test_scenario_grid_compiles(one_chip):
-    """The scenario engine's fused [ensemble=4, K=8] rollout."""
+    """The scenario engine's fused [ensemble=4, K=8] rollout, its four
+    results packed into the one array the server reads back."""
     runner = ScenarioRunner(F8_LIB, DT, ScenarioConfig(ensemble=4),
                             use_pallas=True, interpret=False)
     lib = F8_LIB
     args = (_f32((4, lib.n, lib.size)),
             jax.ShapeDtypeStruct((), jnp.int32),
             _f32((lib.n,)), _f32((8, 50, lib.m)))
-    _compile_has_kernel(runner._roll_impl, args, one_chip)
+    _compile_has_kernel(runner._roll_packed, args, one_chip)
 
 
 def test_fleet_train_step_compiles(one_chip):
