@@ -7,7 +7,7 @@ cost O(budget + log n), with the O(n) scoring fused on device
 loop (no rings, no refits) and drives BOTH planners over the same synthetic
 fleet dynamics:
 
-  * `bucketed` — `PackedRefitScheduler`, the serving default: one fused
+  * `bucketed` — `PackedRefitScheduler`, the server's planner: one fused
     device scoring call + PriorityBuckets winner pops;
   * `reference` — `RefitScheduler`, the O(n log n) dict-sorting oracle
     (fewer ticks; its per-plan cost is the point being retired).

@@ -31,18 +31,19 @@ scheduler.py  Slot-based refit scheduling mirroring serve/engine.ServeEngine's
               admitted / preempted / released by a priority score of
               staleness + divergence, so thousands of tracked objects share
               `refit_slots` concurrent recoveries.  `PackedRefitScheduler`
-              (the serving default) scores the whole fleet in one fused
-              device call over packed arrays (packed.py) and pops only the
-              O(slots) winners through a `PriorityBuckets` queue;
+              (the server's planner) scores the whole fleet in one fused
+              device call over the packed columns (packed.py) and pops only
+              the O(slots) winners through a `PriorityBuckets` queue;
               `RefitScheduler` is the O(n log n) dict-sorting reference the
               equivalence tests hold it to.  `SlotFederation` divides a
               global active-slot budget across per-shard schedulers by
               aggregate pressure (sharded + federated serving).
 
-packed.py     `PackedFleet` — the packed, row-indexed scheduler-state arrays
-              (samples, deploy watermark, divergence, residency) that the
-              server maintains incrementally and the fused scoring /
-              pressure kernels reduce on device.
+packed.py     `PackedFleet` — each twin's serving state in packed, row-indexed
+              columns (samples, deploy watermark, divergence, refit slot and
+              residency, tick stamps, guard verdict) and the twin-id -> row
+              map: the only per-twin state the server holds.  The fused
+              scoring / pressure kernels reduce it on device.
 
 sharded.py    `ShardedTwinServer` — N shards IN ONE PROCESS, each its own
               ring + slot pool + theta store + scheduler, under one

@@ -176,8 +176,7 @@ class DivergenceGuard:
         `div_by_row` is the packed fleet's divergence column
         (twin/packed.py), so this single numpy statement is how the guard
         publishes its view to the scheduler's fused scoring call.  Same
-        float64 arithmetic order as the scalar `smooth`, so the record
-        mirrors stay bit-identical.
+        float64 arithmetic order as the scalar `smooth`.
         """
         a = self.cfg.ema
         rows = np.asarray(rows)

@@ -1,22 +1,18 @@
-"""Packed fleet-state arrays: the scheduler's device-scored data layout.
+"""Packed fleet-state arrays: each twin's serving state, one row per twin.
 
-Up to PR 6 the dict of `TwinRecord`s was the source of truth for everything
-the scheduler reads — samples, deploy watermark, divergence, residency — and
-`RefitScheduler.plan()` re-derived priorities by iterating (and sorting) the
-whole dict in Python every tick.  Fine at 10k twins, fatal at the ROADMAP's
-100k-1M target.
+A `PackedFleet` is the only holder of per-twin serving state: sample
+counters, deploy watermark, divergence, refit-slot residency, tick stamps and
+the guard's last verdict all live in row-indexed numpy columns, and the
+twin-id -> row map assigns the rows.  Every mutation point in the server
+(flush accounting, deploy, guard fold, plan application, refit) writes the
+columns; the scheduler scores the WHOLE fleet in one fused, jit-compiled
+device call (`fleet_scores`) that returns only O(slots) winners, the
+waiting-queue depth, and the federation pressure reduction — so per-tick host
+work is O(budget), not O(twins).
 
-This module flips the layout: **packed, row-indexed numpy arrays are the
-truth** and the record dict is metadata (ids, slot assignments, tick stamps).
-Every mutation point in the server (flush accounting, deploy, guard fold,
-plan application) writes the packed arrays; the scheduler scores the WHOLE
-fleet in one fused, jit-compiled device call (`fleet_scores`) that returns
-only O(slots) winners, the waiting-queue depth, and the federation pressure
-reduction — so per-tick host work is O(budget), not O(twins).
-
-Rows are `TwinRecord.ring_slot` (the TelemetryRing row), so the guard's
-by-row divergence array, the rotation's live set, and the scheduler's score
-arrays all share one indexing scheme.
+Rows are TelemetryRing rows, so the guard's by-row divergence array, the
+rotation's live set, and the scheduler's score arrays all share one indexing
+scheme.
 
 Precision contract: the device kernel scores in float32 (it only has to
 RANK candidates — `jax.lax.top_k` ties break toward the lower row index);
@@ -29,6 +25,7 @@ float32 resolution — semantically a coin-flip tie.
 """
 from __future__ import annotations
 
+import threading
 from functools import partial
 
 import jax
@@ -37,7 +34,9 @@ import numpy as np
 
 from repro.obs import null_span
 
-__all__ = ["PackedFleet", "fleet_scores", "fleet_pressure"]
+__all__ = ["GUARD_KINDS", "PackedFleet", "fleet_scores", "fleet_pressure"]
+
+GUARD_KINDS = ("OK", "REFIT", "ALERT")    # `guard_code` values, in order
 
 
 def _pad_capacity(n: int, floor: int = 64) -> int:
@@ -51,30 +50,38 @@ def _pad_capacity(n: int, floor: int = 64) -> int:
 
 
 class PackedFleet:
-    """Row-indexed scheduler-state arrays for one shard's tracked fleet.
+    """Row-indexed serving state for one shard's tracked fleet.
 
     All arrays have length `capacity` (= the server's `max_twins`); a row is
-    live once `registered[row]` is True.  Sample counters are int32 — the
-    fused call's native dtype, exact in float64 host re-scoring, and good
-    for 8 years of serving at 8 samples/s — so the per-tick device call
-    reads the columns without a conversion pass.  `divergence` (float64) is
-    the guard's exact truth for host re-scoring; `div32` is its float32
-    shadow for the device kernel, written at the same mutation points
-    (guard fold, promote) — `check_mirrors` asserts they never drift.
+    live once `registered[row]` is True, and `row_of` maps a twin id to its
+    row.  Sample counters are int32 — the fused call's native dtype, exact in
+    float64 host re-scoring, and good for 8 years of serving at 8 samples/s
+    — so the per-tick device call reads the columns without a conversion
+    pass.  Two columns are float32/bool shadows the device kernel reads,
+    written at the same mutation points as their host truth: `div32` of
+    `divergence` (float64, the guard's exact truth for host re-scoring) and
+    `resident` of `refit_slot >= 0`; `check_mirrors` asserts they never
+    drift.  `guard_code` indexes `GUARD_KINDS` (the guard's last verdict)
+    and `guard_live` marks rows the guard scores (deployed, with a full
+    guard window of samples).
 
-    Thread-safety matches the server's registry: `register` may be called
-    from ingest threads (the server holds its registration lock and sets
-    `registered` LAST, so a concurrently-planning tick sees either a fully
-    initialized row or an unready one); every other field is written only by
-    the serving thread.
+    Thread-safety: `register` may be called from ingest threads.  It holds
+    the fleet's lock and sets `registered` LAST, so a concurrently-planning
+    tick sees either a fully initialized row or an unready one; `ids()`
+    copies the id map under the same lock.  Every other field is written
+    only by the serving thread.
     """
 
-    __slots__ = ("capacity", "twin_id", "registered", "samples",
-                 "samples_at_deploy", "deployed", "divergence", "div32",
-                 "resident", "residency")
+    _COLUMNS = ("twin_id", "registered", "samples", "samples_at_deploy",
+                "deployed", "divergence", "div32", "resident", "residency",
+                "refit_slot", "deploy_tick", "admitted_tick", "steps_in_slot",
+                "guard_code", "guard_live")
+    __slots__ = ("capacity", "row_of", "_lock") + _COLUMNS
 
     def __init__(self, capacity: int):
         self.capacity = capacity
+        self.row_of: dict[int, int] = {}
+        self._lock = threading.Lock()
         self.twin_id = np.full((capacity,), -1, np.int64)
         self.registered = np.zeros((capacity,), bool)
         self.samples = np.zeros((capacity,), np.int32)
@@ -84,6 +91,12 @@ class PackedFleet:
         self.div32 = np.zeros((capacity,), np.float32)
         self.resident = np.zeros((capacity,), bool)
         self.residency = np.zeros((capacity,), np.int64)
+        self.refit_slot = np.full((capacity,), -1, np.int32)
+        self.deploy_tick = np.full((capacity,), -1, np.int64)
+        self.admitted_tick = np.full((capacity,), -1, np.int64)
+        self.steps_in_slot = np.zeros((capacity,), np.int64)
+        self.guard_code = np.zeros((capacity,), np.int8)
+        self.guard_live = np.zeros((capacity,), bool)
 
     def set_divergence(self, rows, values) -> None:
         """Write divergence truth + its float32 device shadow together —
@@ -92,15 +105,14 @@ class PackedFleet:
         self.div32[rows] = self.divergence[rows]
 
     def check_mirrors(self) -> None:
-        """Assert the float32 shadow matches the float64 truth (tests)."""
+        """Assert the device shadows match their host truth (tests)."""
         if not np.array_equal(self.div32,
                               self.divergence.astype(np.float32)):
             raise AssertionError("div32 shadow drifted from divergence")
+        if not np.array_equal(self.resident, self.refit_slot >= 0):
+            raise AssertionError("resident shadow drifted from refit_slot")
 
     # ------------------------------------------------------------------ #
-    _COLUMNS = ("twin_id", "registered", "samples", "samples_at_deploy",
-                "deployed", "divergence", "div32", "resident", "residency")
-
     def snapshot(self) -> dict:
         """Copy every column into a plain dict of numpy arrays — the
         checkpointable packed-fleet state (twin/recovery.py).  COPIES, not
@@ -109,9 +121,8 @@ class PackedFleet:
         return {c: getattr(self, c).copy() for c in self._COLUMNS}
 
     def load(self, state: dict) -> None:
-        """Restore columns IN PLACE from a `snapshot()` dict.  In-place
-        (`[:]`) because the server's `_div` aliases `divergence` — rebinding
-        the array would silently sever the guard→scheduler data path."""
+        """Restore columns IN PLACE (`[:]`) from a `snapshot()` dict and
+        rebuild the id map from them."""
         for c in self._COLUMNS:
             col = getattr(self, c)
             src = np.asarray(state[c])
@@ -119,13 +130,36 @@ class PackedFleet:
                 raise ValueError(f"packed column {c!r}: snapshot shape "
                                  f"{src.shape} != live shape {col.shape}")
             col[:] = src
+        rows = np.flatnonzero(self.registered)
+        with self._lock:
+            self.row_of = dict(zip(self.twin_id[rows].tolist(),
+                                   rows.tolist()))
 
     # ------------------------------------------------------------------ #
-    def register(self, row: int, twin_id: int) -> None:
-        """Bind a row to a twin id.  `registered` is set last — see class
-        docstring for the concurrent-plan visibility argument."""
-        self.twin_id[row] = twin_id
-        self.registered[row] = True
+    def register(self, twin_id: int) -> int:
+        """The twin's row, assigning the next free one on first sight.
+        `registered` is set last — see class docstring for the
+        concurrent-plan visibility argument."""
+        row = self.row_of.get(twin_id)
+        if row is not None:
+            return row
+        with self._lock:
+            row = self.row_of.get(twin_id)
+            if row is None:
+                row = len(self.row_of)
+                if row >= self.capacity:
+                    raise RuntimeError(
+                        f"server full ({self.capacity} twins)")
+                self.twin_id[row] = twin_id
+                self.registered[row] = True
+                self.row_of[twin_id] = row
+        return row
+
+    def ids(self) -> list[int]:
+        """Registered twin ids, copied under the registration lock (safe to
+        iterate while ingest threads register)."""
+        with self._lock:
+            return list(self.row_of)
 
     # ------------------------------------------------------------------ #
     @classmethod
@@ -139,21 +173,21 @@ class PackedFleet:
         if max_row >= cap:
             raise ValueError(f"ring_slot {max_row} exceeds capacity {cap}")
         fleet = cls(cap)
-        seen_rows: set[int] = set()
         for rec in twins.values():
-            if rec.ring_slot in seen_rows:
-                raise ValueError(f"duplicate ring_slot {rec.ring_slot}")
-            seen_rows.add(rec.ring_slot)
             row = rec.ring_slot
+            if fleet.registered[row]:
+                raise ValueError(f"duplicate ring_slot {row}")
             fleet.twin_id[row] = rec.twin_id
             fleet.samples[row] = rec.samples
             fleet.samples_at_deploy[row] = rec.samples_at_deploy
             fleet.deployed[row] = rec.deployed
-            fleet.divergence[row] = rec.divergence
-            fleet.div32[row] = fleet.divergence[row]
+            fleet.set_divergence(row, rec.divergence)
+            fleet.refit_slot[row] = (-1 if rec.refit_slot is None
+                                     else rec.refit_slot)
             fleet.resident[row] = rec.refit_slot is not None
             fleet.residency[row] = rec.residency
             fleet.registered[row] = True
+            fleet.row_of[rec.twin_id] = row
         return fleet
 
     def slot_rows_from_records(self, twins: dict, slots: int) -> np.ndarray:

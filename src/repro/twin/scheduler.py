@@ -3,8 +3,8 @@
 Mirrors serve/engine.ServeEngine's admission pattern: a FIXED number of refit
 slots (the FleetMerinda fleet axis — one fused train_step advances all of
 them), with twins admitted into and evicted from slots dynamically.  The
-device-side math stays static-shape; all policy runs here on the host over a
-small registry of `TwinRecord`s.
+device-side math stays static-shape; all policy runs here on the host, over
+the fleet's packed per-twin columns (twin/packed.py).
 
 Priority model (computed per twin, higher = refit sooner):
 
@@ -39,22 +39,20 @@ slots a shard may FILL moves.
 
 Two planners implement the SAME admission semantics:
 
-  * `RefitScheduler` — the reference: iterates and sorts the whole
-    `TwinRecord` dict per tick, O(n log n) host cost.  Retained as the
-    equivalence oracle (tests/test_scheduler_equivalence.py) and for tiny
-    fleets.
-  * `PackedRefitScheduler` — the default (twin/server.py): scores the whole
-    fleet in ONE fused jit-compiled device call over packed staleness /
-    divergence arrays (twin/packed.py), pops the O(slots) winners through a
-    `PriorityBuckets` queue, and leaves the host O(budget + log n) work per
-    tick.  The 100k-twin planner.
+  * `PackedRefitScheduler` — the server's planner (twin/server.py): scores
+    the whole fleet in ONE fused jit-compiled device call over the packed
+    staleness / divergence columns (twin/packed.py), pops the O(slots)
+    winners through a `PriorityBuckets` queue, and leaves the host
+    O(budget + log n) work per tick.  The 100k-twin planner.
+  * `RefitScheduler` — the tests' oracle: iterates and sorts a `TwinRecord`
+    dict per tick, O(n log n) host cost; tests/test_scheduler_equivalence.py
+    holds the packed planner to its plans.
 """
 from __future__ import annotations
 
 import heapq
 import math
 import time
-import warnings
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -69,7 +67,9 @@ __all__ = ["TwinRecord", "SchedulerConfig", "SchedulePlan", "SchedulerMetrics",
 
 @dataclass
 class TwinRecord:
-    """Host-side registry entry for one tracked object."""
+    """One tracked object's serving state as a plain record: the server's
+    read-only `twins` view builds one from the twin's packed row, and
+    `RefitScheduler` plans over a dict of them."""
     twin_id: int
     ring_slot: int                    # row in TelemetryRing
     refit_slot: int | None = None     # FleetMerinda slot, None if waiting
@@ -297,11 +297,8 @@ class RefitScheduler:
         Units: residency thresholds (`min_residency`, `max_residency`) are
         serving TICKS, not seconds or train steps; `min_samples` is ring
         telemetry samples.  Host cost is O(n log n) in the number of
-        tracked twins (two sorts per tick) — the reason
-        `PackedRefitScheduler` is the serving default; this planner is the
-        semantics oracle.  Not thread-safe by itself; the server passes
-        a `twin_snapshot()` registry copy so concurrent `ingest`
-        registrations cannot race the iteration.
+        tracked twins (two sorts per tick) — the reason the server plans
+        with `PackedRefitScheduler`; this planner is the semantics oracle.
 
         Iteration is in twin_id order so equal-priority decisions are
         deterministic across runs.
@@ -459,8 +456,8 @@ class PackedRefitScheduler:
     def plan_records(self, twins: dict[int, TwinRecord],
                      max_active: int | None = None) -> SchedulePlan:
         """Reference-interop entry: plan from a `TwinRecord` dict by packing
-        it first.  Used by the equivalence tests and tools; the server calls
-        `plan()` directly on its incrementally-maintained fleet."""
+        it first.  Used by the equivalence tests; the server calls `plan()`
+        directly on its own packed fleet."""
         fleet = PackedFleet.from_records(twins)
         slot_rows = fleet.slot_rows_from_records(twins, self.cfg.slots)
         return self.plan(fleet, slot_rows, max_active=max_active)
@@ -586,55 +583,13 @@ class PackedRefitScheduler:
 # --------------------------------------------------------------------------- #
 # Federation: divide a global active-slot budget across per-shard schedulers
 # --------------------------------------------------------------------------- #
-@dataclass(frozen=True, init=False)
+@dataclass(frozen=True)
 class FederationConfig:
     """Slot-federation knobs; field names match `FleetTopologyConfig`
-    (twin/service.py), the config base both deployment shapes extend.
-
-    The pre-federation names (`min_slots=`, `smooth=`) are accepted as
-    deprecated keyword aliases for one release — they warn and route to the
-    canonical fields."""
+    (twin/service.py), the config base both deployment shapes extend."""
     total_slots: int                # global active-refit budget, all shards
     min_shard_slots: int = 1        # per-shard grant floor (keeps shards live)
     pressure_smooth: float = 0.5    # EMA weight of the newest pressure reading
-
-    def __init__(self, total_slots: int, min_shard_slots: int | None = None,
-                 pressure_smooth: float | None = None, *,
-                 min_slots: int | None = None, smooth: float | None = None):
-        for old, new, val in (("min_slots", "min_shard_slots", min_slots),
-                              ("smooth", "pressure_smooth", smooth)):
-            if val is not None:
-                warnings.warn(
-                    f"FederationConfig({old}=...) is deprecated; use "
-                    f"{new}=... (one-release shim, twin/service.py "
-                    "consolidation)", DeprecationWarning, stacklevel=2)
-        if min_slots is not None:
-            if min_shard_slots is not None:
-                raise TypeError("pass min_shard_slots OR min_slots, not both")
-            min_shard_slots = min_slots
-        if smooth is not None:
-            if pressure_smooth is not None:
-                raise TypeError("pass pressure_smooth OR smooth, not both")
-            pressure_smooth = smooth
-        object.__setattr__(self, "total_slots", total_slots)
-        object.__setattr__(self, "min_shard_slots",
-                           1 if min_shard_slots is None else min_shard_slots)
-        object.__setattr__(self, "pressure_smooth",
-                           0.5 if pressure_smooth is None else pressure_smooth)
-
-    @property
-    def min_slots(self) -> int:
-        """Deprecated alias of `min_shard_slots` (one-release shim)."""
-        warnings.warn("FederationConfig.min_slots is deprecated; read "
-                      "min_shard_slots", DeprecationWarning, stacklevel=2)
-        return self.min_shard_slots
-
-    @property
-    def smooth(self) -> float:
-        """Deprecated alias of `pressure_smooth` (one-release shim)."""
-        warnings.warn("FederationConfig.smooth is deprecated; read "
-                      "pressure_smooth", DeprecationWarning, stacklevel=2)
-        return self.pressure_smooth
 
 
 class SlotFederation:

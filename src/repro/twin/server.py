@@ -15,15 +15,19 @@ One `tick()` is a full serving cycle over the whole tracked fleet:
                 so guard cost is O(budget), not O(twins),
     3. SCHEDULE admit/evict/release twins over the bounded refit-slot pool
                 by staleness + divergence priority (twin/scheduler.py).
-                The default `PackedRefitScheduler` scores the WHOLE fleet in
-                one fused device call over packed arrays (twin/packed.py)
-                and pops only the O(slots) winners on the host; a federation
+                `PackedRefitScheduler` scores the WHOLE fleet in one fused
+                device call over the packed columns (twin/packed.py) and
+                pops only the O(slots) winners on the host; a federation
                 layer (twin/sharded.py) can cap the active pool via
                 `set_active_slots`,
     4. REFIT    `steps_per_tick` fused FleetMerinda.train_step calls over all
                 slots at once (the bounded compute budget),
     5. DEPLOY   recover_all on slots whose twin has trained past
                 `deploy_after`, scattered into the serving theta store.
+
+Each twin's serving state lives in one place, the server's `PackedFleet`
+columns; `server.twins` is a read-only view that builds a `TwinRecord` from a
+row when it is read.
 
 Every fused call has a FIXED shape (refit_slots / max_twins / guard budget),
 so steady-state serving compiles exactly once; unassigned refit slots are
@@ -49,8 +53,8 @@ twin's newest telemetry — the collision-avoidance lookahead.
 """
 from __future__ import annotations
 
-import threading
 import time
+from collections.abc import Mapping
 from dataclasses import dataclass, field
 from functools import partial
 
@@ -65,15 +69,14 @@ from repro.kernels.rk4.ops import rk4_poly_solve
 from repro.obs import MetricRegistry, Tracer
 from repro.twin.monitor import (DivergenceGuard, GuardConfig, GuardEvent,
                                 GuardInstruments, GuardRotation)
-from repro.twin.packed import PackedFleet
+from repro.twin.packed import GUARD_KINDS, PackedFleet
 from repro.twin.recovery import (DegradationConfig, DegradationEvent,
                                  DegradationPolicy)
 from repro.twin.scenario import (ScenarioConfig, ScenarioRefused,
                                  ScenarioResult, ScenarioRunner, effective_k)
 from repro.twin.service import DeadlineConfig
-from repro.twin.scheduler import (PackedRefitScheduler, RefitScheduler,
-                                  SchedulerConfig, SchedulePlan,
-                                  SchedulerMetrics, TwinRecord)
+from repro.twin.scheduler import (PackedRefitScheduler, SchedulerConfig,
+                                  SchedulePlan, SchedulerMetrics, TwinRecord)
 from repro.twin.stream import (FlushBatch, RingConfig, StagingBuffer,
                                StagingOverflow, TelemetryRing, prepare_flush)
 
@@ -137,9 +140,6 @@ class TwinServerConfig(DeadlineConfig):
     min_residency: int = 8
     max_residency: int = 64
     release_divergence: float = 0.05
-    scheduler: str = "bucketed"       # "bucketed": PackedRefitScheduler
-                                      # (device-fused scoring); "reference":
-                                      # the O(n log n) dict-sorting oracle
     flush_pad: int = 8                # chunk-length quantum (bounds retraces)
     degradation: DegradationConfig = DegradationConfig()
                                       # deadline-aware shed ladder
@@ -242,23 +242,14 @@ class TwinServer:
             evict_margin=cfg.evict_margin, min_residency=cfg.min_residency,
             max_residency=cfg.max_residency,
             release_divergence=cfg.release_divergence)
-        sched_metrics = SchedulerMetrics.create(self.metrics, self._labels)
-        if cfg.scheduler == "bucketed":
-            self.scheduler = PackedRefitScheduler(sched_cfg,
-                                                  metrics=sched_metrics,
-                                                  span=self.tracer.span)
-        elif cfg.scheduler == "reference":
-            self.scheduler = RefitScheduler(sched_cfg, metrics=sched_metrics)
-        else:
-            raise ValueError(f"unknown scheduler {cfg.scheduler!r} "
-                             "(expected 'bucketed' or 'reference')")
-        # packed-arrays-as-truth scheduler state (twin/packed.py): every
-        # mutation point below (flush accounting, deploy, guard fold, plan
-        # apply, refit residency) writes BOTH the record and its packed row,
-        # so the fused scoring call never rebuilds from the dict.  The
-        # record dict stays the metadata mirror (ids, slots, tick stamps)
-        # that tests/examples and the reference planner read.
+        self.scheduler = PackedRefitScheduler(
+            sched_cfg, metrics=SchedulerMetrics.create(self.metrics,
+                                                       self._labels),
+            span=self.tracer.span)
+        # every per-twin fact, by ring row (twin/packed.py); `twins` is a
+        # read-only view of it
         self.packed = PackedFleet(cfg.max_twins)
+        self.twins = _TwinView(self.packed)
         self._max_active: int | None = None   # federation cap (None: all)
 
         self._rotation = (None if cfg.guard_budget is None else
@@ -267,27 +258,14 @@ class TwinServer:
                                         if cfg.guard_carry is None
                                         else cfg.guard_carry))
 
-        self.twins: dict[int, TwinRecord] = {}
-        self._row2rec: dict[int, TwinRecord] = {}     # ring row -> record
-        # guard-eligible set (deployed + enough samples), maintained
-        # INCREMENTALLY at deploy/flush time: the guard must not rescan all
-        # 10k records per tick, or its cost is O(twins) again on the host
-        # side no matter how small the fused budget is.  _div mirrors each
-        # record's EMA score by ring row (the rotation's vectorized
-        # carry-over scan reads it); since the packed-fleet refactor _div IS
-        # the fleet's divergence column (same array object), so guard folds
-        # feed the scheduler's fused scoring with no extra copy.  _live_rows
-        # caches the sorted row array, rebuilt only when membership changes.
-        self._guard_live: dict[int, TwinRecord] = {}  # ring row -> record
+        # guard-eligible rows (deployed + enough samples): the packed
+        # `guard_live` column, set at flush/deploy time; _live_rows caches
+        # its sorted row array, rebuilt only when membership changes
         self._guard_min = cfg.guard.window + 1
-        self._div = self.packed.divergence
         self._live_rows = np.empty((0,), np.int64)
         self._live_dirty = False
-        self._reg_lock = threading.Lock()             # async ingest registers
-        self._guard_state: dict[int, str] = {}        # twin_id -> last kind
         self._slot_ring = np.full((cfg.refit_slots,), self._scratch,
                                   dtype=np.int32)     # refit slot -> ring row
-        self._slot_twin: dict[int, int] = {}          # refit slot -> twin_id
         L = self.fleet.model.lib.size
         self._theta = jnp.zeros((cfg.max_twins + 1, m.n, L))
         # per-twin ring of recently served thetas (scenario confidence
@@ -302,7 +280,6 @@ class TwinServer:
                                      depth=cfg.ingest_depth)
                       if cfg.async_ingest else None)
         self.tick_count = 0
-        self._n_deployed = 0
         self.inject_delay_s = 0.0     # chaos straggler (twin/recovery.py):
                                       # slept INSIDE the timed tick region so
                                       # the degradation policy sees the stall
@@ -412,35 +389,22 @@ class TwinServer:
             labels=lab)
 
     # ------------------------------------------------------------------ #
-    def register(self, twin_id: int) -> TwinRecord:
-        """Start tracking an object; assigns its telemetry ring row."""
-        rec = self.twins.get(twin_id)
-        if rec is not None:
-            return rec
-        with self._reg_lock:
-            rec = self.twins.get(twin_id)
-            if rec is not None:
-                return rec
-            row = len(self.twins)
-            if row >= self.cfg.max_twins:
-                raise RuntimeError(f"server full ({self.cfg.max_twins} twins)")
-            rec = TwinRecord(twin_id=twin_id, ring_slot=row)
-            self.twins[twin_id] = rec
-            self._row2rec[row] = rec
-            self._guard_state[twin_id] = "OK"
-            self.packed.register(row, twin_id)
-            return rec
+    def register(self, twin_id: int) -> int:
+        """Start tracking an object; returns its telemetry ring row."""
+        return self.packed.register(twin_id)
 
     def twin_snapshot(self) -> dict[int, TwinRecord]:
         """Registry copy safe to iterate while ingest threads register."""
-        with self._reg_lock:
-            return dict(self.twins)
+        return dict(self.twins)
 
-    def _guard_add(self, rec: TwinRecord) -> None:
-        """Admit a record to the guard-eligible set (idempotent)."""
-        if rec.ring_slot not in self._guard_live:
-            self._guard_live[rec.ring_slot] = rec
-            self.packed.set_divergence(rec.ring_slot, rec.divergence)
+    def _guard_admit(self, rows: np.ndarray) -> None:
+        """Admit the deployed `rows` with a full guard window of samples to
+        the guard-eligible set (idempotent)."""
+        p = self.packed
+        new = rows[~p.guard_live[rows] & p.deployed[rows]
+                   & (p.samples[rows] >= self._guard_min)]
+        if new.size:
+            p.guard_live[new] = True
             self._live_dirty = True
 
     # ------------------------------------------------------------------ #
@@ -462,7 +426,7 @@ class TwinServer:
         windows.  `force=True` bypasses the bound entirely (crash-recovery
         replay, twin/recovery.py).
         """
-        rec = self.register(twin_id)
+        row = self.register(twin_id)
         y = np.atleast_2d(np.asarray(y, np.float32))
         C = y.shape[0]
         m = self.cfg.merinda.m
@@ -471,9 +435,9 @@ class TwinServer:
         if C > self.cfg.capacity:
             raise ValueError("chunk larger than ring capacity")
         try:
-            self._staging.append(rec.ring_slot, y, u, force=force)
+            self._staging.append(row, y, u, force=force)
         except StagingOverflow:
-            self._ingest_backpressure(rec.ring_slot, y, u)
+            self._ingest_backpressure(row, y, u)
         if self._pump is not None:
             self._pump.kick()
 
@@ -544,12 +508,12 @@ class TwinServer:
             if batch.dropped:
                 self._m_dropped.inc(batch.dropped)
                 self._m_overflow.inc()
-            for row, raw in batch.received.items():
-                rec = self._row2rec[row]
-                rec.samples += raw
-                self.packed.samples[row] = rec.samples
-                if rec.deployed and rec.samples >= self._guard_min:
-                    self._guard_add(rec)
+            rows = np.fromiter(batch.received, np.int64,
+                               count=len(batch.received))
+            raw = np.fromiter(batch.received.values(), np.int32,
+                              count=len(rows))
+            self.packed.samples[rows] += raw
+            self._guard_admit(rows)
             self._rstate = self.ring.ingest(
                 self._rstate, jnp.asarray(batch.slots), jnp.asarray(batch.ys),
                 jnp.asarray(batch.us), jnp.asarray(batch.counts))
@@ -605,12 +569,9 @@ class TwinServer:
 
     def refit_pressure(self) -> float:
         """Aggregate staleness+divergence refit demand — the federation's
-        rebalance signal.  Bucketed scheduler: one fused device reduction
-        over the packed arrays; reference scheduler: the O(twins) host scan
-        over a registry snapshot."""
-        if isinstance(self.scheduler, PackedRefitScheduler):
-            return self.scheduler.pressure(self.packed)
-        return self.scheduler.pressure(self.twin_snapshot())
+        rebalance signal, as one fused device reduction over the packed
+        columns."""
+        return self.scheduler.pressure(self.packed)
 
     # ------------------------------------------------------------------ #
     def _hist_push(self, rows: np.ndarray, thetas) -> None:
@@ -629,16 +590,12 @@ class TwinServer:
     def deploy(self, twin_id: int, theta) -> None:
         """Install a theta for `twin_id` directly (warm start from an offline
         recovery — lets a fleet come up serving while online refits rotate)."""
-        rec = self.register(twin_id)
+        row = self.register(twin_id)
         theta = jnp.asarray(theta)
-        self._theta = self._theta.at[rec.ring_slot].set(theta)
-        self._hist_push(np.asarray([rec.ring_slot], np.int64), theta[None])
-        self._mark_deployed(rec)
-        rec.samples_at_deploy = rec.samples
-        self.packed.samples_at_deploy[rec.ring_slot] = rec.samples
-        rec.deploy_tick = self.tick_count
-        if rec.samples >= self._guard_min:
-            self._guard_add(rec)
+        self._theta = self._theta.at[row].set(theta)
+        rows = np.asarray([row], np.int64)
+        self._hist_push(rows, theta[None])
+        self._mark_deployed(rows)
 
     def deploy_many(self, twin_ids, thetas) -> None:
         """Warm-start a whole fleet in one scatter: thetas [B, n, L] (or a
@@ -650,33 +607,37 @@ class TwinServer:
         Serving-thread only (mutates the device theta store); not safe to
         call concurrently with `tick()`.
         """
-        recs = [self.register(t) for t in twin_ids]
-        rows = np.asarray([r.ring_slot for r in recs], np.int32)
+        rows = np.asarray([self.register(t) for t in twin_ids], np.int32)
         thetas = jnp.asarray(thetas)
         if thetas.ndim == 2:
-            thetas = jnp.broadcast_to(thetas, (len(recs),) + thetas.shape)
+            thetas = jnp.broadcast_to(thetas, (len(rows),) + thetas.shape)
         self._theta = self._theta.at[jnp.asarray(rows)].set(thetas)
-        self._hist_push(rows.astype(np.int64), thetas)
-        for rec in recs:
-            self._mark_deployed(rec)
-            rec.samples_at_deploy = rec.samples
-            self.packed.samples_at_deploy[rec.ring_slot] = rec.samples
-            rec.deploy_tick = self.tick_count
-            if rec.samples >= self._guard_min:
-                self._guard_add(rec)
+        rows = rows.astype(np.int64)
+        self._hist_push(rows, thetas)
+        self._mark_deployed(rows)
 
-    def _mark_deployed(self, rec: TwinRecord) -> None:
-        if not rec.deployed:
-            rec.deployed = True
-            self.packed.deployed[rec.ring_slot] = True
-            self._n_deployed += 1
+    def _mark_deployed(self, rows: np.ndarray) -> None:
+        """`rows` now serve a theta: reset their staleness watermark, stamp
+        the tick, and admit the ready ones to the guard."""
+        p = self.packed
+        p.deployed[rows] = True
+        p.samples_at_deploy[rows] = p.samples[rows]
+        p.deploy_tick[rows] = self.tick_count
+        self._guard_admit(rows)
 
     # ------------------------------------------------------------------ #
     def _update_divergence(self, shed: bool = False
                            ) -> tuple[list[GuardEvent], int]:
+        """Score guard-eligible rows, fold the scores into the divergence
+        column, and report transitions — in ring-row order for the full
+        scan, in pick order for the rotation."""
         gw = self.cfg.guard.window
-        live = self._guard_live       # maintained incrementally, O(1)/tick
-        if not live:
+        p = self.packed
+        if self._live_dirty:
+            self._live_rows = np.flatnonzero(p.guard_live)
+            self._live_dirty = False
+        live = self._live_rows
+        if not live.size:
             return [], 0
         if self._rotation is None:
             # full scan: one fused call over the whole store (O(twins)).
@@ -690,28 +651,22 @@ class TwinServer:
             scores = self.guard.score(self._theta[:-1], ys, us)
             with self.tracer.span("sync", site="guard.scores"):
                 scores = np.asarray(scores)
-            recs = list(live.values())
-            srows = np.fromiter((r.ring_slot for r in recs), np.int64,
-                                count=len(recs))
+            srows = live
             raw = scores[srows]
         else:
             # budgeted rotation: fixed-size fused call (O(budget)).
             # Degraded: a SMALLER fixed width (budget // guard_shrink, no
             # carry) — one extra compile the first time the ladder engages,
             # then a genuinely cheaper rollout until pressure clears.
-            if self._live_dirty:
-                self._live_rows = np.fromiter(sorted(live), np.int64,
-                                              count=len(live))
-                self._live_dirty = False
             if shed:
                 width = max(1, self._rotation.budget
                             // max(1, self.cfg.degradation.guard_shrink))
-                pick = self._rotation.select(self._live_rows, self._div,
+                pick = self._rotation.select(live, p.divergence,
                                              self.cfg.guard.refit_threshold,
                                              budget=width, carry=0)
             else:
                 width = self._rotation.size
-                pick = self._rotation.select(self._live_rows, self._div,
+                pick = self._rotation.select(live, p.divergence,
                                              self.cfg.guard.refit_threshold)
             rows_np = np.full((width,), self._scratch, np.int32)
             rows_np[:len(pick)] = pick
@@ -720,29 +675,28 @@ class TwinServer:
             scores = self.guard.score(self._theta[rows], ys, us)
             with self.tracer.span("sync", site="guard.scores"):
                 scores = np.asarray(scores)
-            recs = [live[int(row)] for row in pick]
             srows = np.asarray(pick, np.int64)
-            raw = scores[:len(recs)]
+            raw = scores[:len(srows)]
         # one vectorized EMA fold publishes the smoothed scores into the
-        # packed divergence column (_div IS packed.divergence); the record
-        # fields are mirrors of the same values
-        smoothed = self.guard.fold_into(self._div, srows, raw)
-        self.packed.div32[srows] = smoothed   # float32 shadow for the kernel
+        # packed divergence column the scheduler scores
+        smoothed = self.guard.fold_into(p.divergence, srows, raw)
+        p.div32[srows] = smoothed             # float32 shadow for the kernel
         events: list[GuardEvent] = []
         score_hist = self._guard_obs.score
-        for rec, score, div in zip(recs, raw, smoothed):
+        for row, tid, score, div in zip(srows.tolist(),
+                                        p.twin_id[srows].tolist(), raw,
+                                        smoothed.tolist()):
             score_hist.observe(float(score))
-            rec.divergence = float(div)
-            ev = self.guard.judge(rec.twin_id, rec.divergence, self.tick_count)
-            kind = ev.kind if ev else "OK"
-            if kind != self._guard_state[rec.twin_id]:
-                self._guard_state[rec.twin_id] = kind
+            ev = self.guard.judge(tid, div, self.tick_count)
+            code = GUARD_KINDS.index(ev.kind) if ev else 0
+            if code != p.guard_code[row]:
+                p.guard_code[row] = code
                 if ev:
                     events.append(ev)
                     self._guard_obs.events[ev.kind].inc()
         self.events.extend(events)
-        self._guard_obs.scored.inc(len(recs))
-        return events, len(recs)
+        self._guard_obs.scored.inc(len(srows))
+        return events, len(srows)
 
     # ------------------------------------------------------------------ #
     def _slot_windows(self):
@@ -750,30 +704,31 @@ class TwinServer:
         return self.ring.windows(self._rstate, rows, window=self.cfg.window,
                                  stride=self.cfg.stride, length=self.span)
 
+    def _occupied_slots(self) -> np.ndarray:
+        """Refit slots holding a twin, ascending."""
+        return np.flatnonzero(self._slot_ring != self._scratch)
+
     def _apply_plan(self, plan: SchedulePlan) -> None:
-        packed = self.packed
-        for tid in plan.evict + plan.release:
-            rec = self.twins[tid]
-            self._slot_ring[rec.refit_slot] = self._scratch
-            self._slot_twin.pop(rec.refit_slot, None)
-            rec.refit_slot = None
-            rec.residency = rec.steps_in_slot = 0
-            packed.resident[rec.ring_slot] = False
-            packed.residency[rec.ring_slot] = 0
+        p = self.packed
+        gone = [p.row_of[tid] for tid in plan.evict + plan.release]
+        if gone:
+            self._slot_ring[p.refit_slot[gone]] = self._scratch
+            p.refit_slot[gone] = -1
+            p.resident[gone] = False
+            p.residency[gone] = p.steps_in_slot[gone] = 0
         if not plan.admit:
             return
+        n = len(plan.admit)
         admit = np.full((2, self.cfg.refit_slots), -1, np.int32)
         admit[1] = self._scratch
-        for i, (slot, tid) in enumerate(plan.admit):
-            rec = self.twins[tid]
-            admit[:, i] = slot, rec.ring_slot
-            rec.refit_slot = slot
-            rec.admitted_tick = self.tick_count
-            rec.residency = rec.steps_in_slot = 0
-            packed.resident[rec.ring_slot] = True
-            packed.residency[rec.ring_slot] = 0
-            self._slot_ring[slot] = rec.ring_slot
-            self._slot_twin[slot] = tid
+        admit[:, :n] = [[slot for slot, _ in plan.admit],
+                        [p.row_of[tid] for _, tid in plan.admit]]
+        slots, rows = admit[:, :n]
+        p.refit_slot[rows] = slots
+        p.resident[rows] = True
+        p.admitted_tick[rows] = self.tick_count
+        p.residency[rows] = p.steps_in_slot[rows] = 0
+        self._slot_ring[slots] = rows
         self._fstate, self._key = _admit(
             self.ring, self.fleet, self._rstate, self._fstate, admit,
             self._key, window=self.cfg.window, stride=self.cfg.stride,
@@ -783,8 +738,11 @@ class TwinServer:
 
     def _refit(self, defer: bool = False, skip_promote: bool = False
                ) -> float | None:
-        if not self._slot_twin:
+        p = self.packed
+        slots = self._occupied_slots()
+        if not slots.size:
             return None
+        rows = self._slot_ring[slots]
         if defer:
             # degraded (level >= 2): slots hold — no train steps, residency
             # frozen.  Candidates that already converged may still ship
@@ -792,10 +750,9 @@ class TwinServer:
             # than steps_per_tick train steps, and a finished model serving
             # beats a finished model waiting out an overload.
             if not skip_promote:
-                deployable = [
-                    slot for slot, tid in self._slot_twin.items()
-                    if self.twins[tid].steps_in_slot >= self.cfg.deploy_after]
-                if deployable:
+                deployable = slots[p.steps_in_slot[rows]
+                                   >= self.cfg.deploy_after]
+                if deployable.size:
                     y_win, u_win = self._slot_windows()
                     with self.tracer.span("promote"):
                         self._promote(deployable, y_win, u_win)
@@ -810,16 +767,11 @@ class TwinServer:
                 loss_vec = np.asarray(loss_vec)
             # report loss over ASSIGNED slots only — scratch-parked slots
             # train on zero windows and would dilute the mean toward zero
-            loss = float(np.mean(loss_vec[sorted(self._slot_twin)]))
-        deployable = []
-        for slot, tid in self._slot_twin.items():
-            rec = self.twins[tid]
-            rec.steps_in_slot += self.cfg.steps_per_tick
-            rec.residency += 1
-            self.packed.residency[rec.ring_slot] = rec.residency
-            if rec.steps_in_slot >= self.cfg.deploy_after:
-                deployable.append(slot)
-        if deployable and not skip_promote:
+            loss = float(np.mean(loss_vec[slots]))
+        p.steps_in_slot[rows] += self.cfg.steps_per_tick
+        p.residency[rows] += 1
+        deployable = slots[p.steps_in_slot[rows] >= self.cfg.deploy_after]
+        if deployable.size and not skip_promote:
             with self.tracer.span("promote"):
                 self._promote(deployable, y_win, u_win)
         return loss
@@ -845,39 +797,26 @@ class TwinServer:
         inc = self.guard.score(self._theta[rows], ys_g, us_g)
         with self.tracer.span("sync", site="promote.inc"):
             inc = np.asarray(inc)
-        targets = np.full((self.cfg.refit_slots,), self._scratch,
-                          dtype=np.int32)
-        promoted = set()
-        for slot in deployable:
-            rec = self.twins[self._slot_twin[slot]]
-            healthy_inc = rec.deployed and inc[slot] < thresh
-            better = cand[slot] < self.cfg.promote_margin * inc[slot]
-            if better or (not healthy_inc and cand[slot] < thresh):
-                targets[slot] = rec.ring_slot
-                promoted.add(slot)
-            elif healthy_inc:
-                # candidate lost, but the serving model is still healthy:
-                # count this as a completed review so the twin's staleness
-                # resets and it stops hogging a refit slot.
-                rec.samples_at_deploy = rec.samples
-                self.packed.samples_at_deploy[rec.ring_slot] = rec.samples
-        if promoted:
+        p = self.packed
+        drows = self._slot_ring[deployable]
+        cand_d, inc_d = cand[deployable], inc[deployable]
+        healthy_inc = p.deployed[drows] & (inc_d < thresh)
+        better = cand_d < self.cfg.promote_margin * inc_d
+        won = better | (~healthy_inc & (cand_d < thresh))
+        # candidate lost, but the serving model is still healthy: count
+        # this as a completed review so the twin's staleness resets and it
+        # stops hogging a refit slot.
+        reviewed = drows[~won & healthy_inc]
+        p.samples_at_deploy[reviewed] = p.samples[reviewed]
+        if won.any():
+            slots, prows = deployable[won], drows[won].astype(np.int64)
+            targets = np.full((self.cfg.refit_slots,), self._scratch,
+                              dtype=np.int32)
+            targets[slots] = prows
             self._theta = self._theta.at[jnp.asarray(targets)].set(thetas)
-            slots = sorted(promoted)
-            prows = np.asarray(
-                [self.twins[self._slot_twin[s]].ring_slot for s in slots],
-                np.int64)
             self._hist_push(prows, thetas[jnp.asarray(slots)])
-        for slot in promoted:
-            rec = self.twins[self._slot_twin[slot]]
-            self._mark_deployed(rec)
-            rec.samples_at_deploy = rec.samples
-            self.packed.samples_at_deploy[rec.ring_slot] = rec.samples
-            rec.deploy_tick = self.tick_count
-            rec.divergence = float(min(cand[slot], 1e6))
-            self.packed.set_divergence(rec.ring_slot, rec.divergence)
-            if rec.samples >= self._guard_min:
-                self._guard_add(rec)
+            p.set_divergence(prows, np.minimum(cand_d[won], 1e6))
+            self._mark_deployed(prows)
 
     # ------------------------------------------------------------------ #
     def tick(self) -> TickReport:
@@ -892,9 +831,8 @@ class TwinServer:
         Threading: must be called from the single serving thread (device
         state — ring, fleet, theta store — is single-threaded by design).
         `ingest()` MAY run concurrently on sensor threads; the staging
-        buffer's lock is the only synchronization point between them, and a
-        registry snapshot is taken before scheduling so concurrent
-        registrations cannot race dict iteration.
+        buffer's lock and the packed fleet's registration lock are the only
+        synchronization points between them.
 
         Fused-call costs per tick: flush is one scatter over the reporting
         twins (pow2-bucketed shapes), guard is O(guard_budget + carry)
@@ -920,22 +858,13 @@ class TwinServer:
                     self._m_shed["guard"].inc()
                 events, n_guarded = self._update_divergence(shed=shed_guard)
             t2 = time.perf_counter()
-            # bucketed path: plan straight off the packed arrays (a twin
-            # registered mid-plan is visible only once `registered` flips,
-            # and with 0 samples it cannot be ready — no snapshot needed).
-            # reference path: snapshot the registry, since async ingest
-            # threads may register new twins mid-tick and dict iteration
-            # must not race those inserts.
+            # plan straight off the packed columns: a twin registered
+            # mid-plan is visible only once `registered` flips, and with 0
+            # samples it cannot be ready
             with span("schedule"):
                 with span("plan"):
-                    if isinstance(self.scheduler, PackedRefitScheduler):
-                        plan = self.scheduler.plan(
-                            self.packed, self._slot_ring,
-                            max_active=self._max_active)
-                    else:
-                        plan = self.scheduler.plan(
-                            self.twin_snapshot(),
-                            max_active=self._max_active)
+                    plan = self.scheduler.plan(self.packed, self._slot_ring,
+                                               max_active=self._max_active)
                 with span("admit", admitted=len(plan.admit)):
                     self._apply_plan(plan)
             t3 = time.perf_counter()
@@ -960,46 +889,51 @@ class TwinServer:
         if deg_ev is not None:
             self._m_deg_trans[
                 "up" if deg_ev.to_level > deg_ev.from_level else "down"].inc()
-        n_active = len(self._slot_twin)
+        p = self.packed
+        n_active = len(self._occupied_slots())
+        n_twins = len(p.row_of)
         if n_active:
             self._m_refreshes.inc(n_active)
-        self._m_tracked.set(len(self.twins))
-        self._m_deployed.set(self._n_deployed)
+        self._m_tracked.set(n_twins)
+        self._m_deployed.set(int(np.count_nonzero(p.deployed)))
         self._m_active.set(n_active)
         self._m_staging.set(self._staging.pending_samples())
         if self._pump is not None:
             self._m_queue.set(self._pump.queue_depth())
-        self._guard_obs.live.set(len(self._guard_live))
+        self._guard_obs.live.set(int(np.count_nonzero(p.guard_live)))
         return TickReport(
             tick=self.tick_count, latency_s=latency,
             deadline_met=latency <= self.cfg.deadline_s, loss=loss,
             events=events, admitted=plan.admit, evicted=plan.evict,
             released=plan.release, n_active=n_active,
-            n_twins=len(self.twins), n_guarded=n_guarded,
+            n_twins=n_twins, n_guarded=n_guarded,
             degraded_level=deg.level,
             degradation_events=[deg_ev] if deg_ev is not None else [])
 
     # ------------------------------------------------------------------ #
+    def _serving_row(self, twin_id: int, use: str) -> int:
+        """Ring row of a twin with a deployed model and telemetry (an
+        all-zero ring would start a rollout from the origin instead of the
+        twin's actual state)."""
+        row = self.packed.row_of[twin_id]
+        if not self.packed.deployed[row]:
+            raise RuntimeError(f"twin {twin_id} has no deployed model")
+        if self.packed.samples[row] < 1:
+            raise RuntimeError(f"twin {twin_id} has no telemetry to {use}")
+        return row
+
     def predict(self, twin_id: int, horizon: int, us=None):
         """Roll the deployed model `horizon` steps from the newest telemetry.
 
         Returns ys [horizon+1, n] (index 0 = the newest observed state).
         """
-        rec = self.twins[twin_id]
-        if not rec.deployed:
-            raise RuntimeError(f"twin {twin_id} has no deployed model")
-        if rec.samples < 1:
-            # the ring is still all zeros — a rollout would silently start
-            # from the origin instead of the twin's actual state
-            raise RuntimeError(f"twin {twin_id} has no telemetry to "
-                               "predict from")
-        ys, _ = self.ring.latest(self._rstate,
-                                 jnp.asarray([rec.ring_slot]), 0)
+        row = self._serving_row(twin_id, "predict from")
+        ys, _ = self.ring.latest(self._rstate, jnp.asarray([row]), 0)
         y0 = ys[:, -1, :]                                    # [1, n]
         m = self.cfg.merinda.m
         us = (jnp.zeros((1, horizon, m)) if us is None
               else jnp.asarray(us, jnp.float32).reshape(1, horizon, m))
-        out = rk4_poly_solve(self._theta[rec.ring_slot][None], y0, us,
+        out = rk4_poly_solve(self._theta[row][None], y0, us,
                              dt=self.cfg.merinda.dt, library=self.fleet.model.lib,
                              use_pallas=self.cfg.merinda.use_pallas,
                              interpret=self.cfg.merinda.interpret)
@@ -1019,12 +953,7 @@ class TwinServer:
 
         Serving-thread only, like `predict` (reads device ring state).
         """
-        rec = self.twins[twin_id]
-        if not rec.deployed:
-            raise RuntimeError(f"twin {twin_id} has no deployed model")
-        if rec.samples < 1:
-            raise RuntimeError(f"twin {twin_id} has no telemetry to "
-                               "roll scenarios from")
+        row = self._serving_row(twin_id, "roll scenarios from")
         if horizon < 1:
             raise ValueError(f"horizon must be >= 1, got {horizon}")
         scfg = self.cfg.scenario
@@ -1058,8 +987,8 @@ class TwinServer:
             with self.tracer.span("gather"):
                 theta_hist, y0 = _scenario_gather(
                     self.ring, self._rstate, self._theta_hist,
-                    np.int32(rec.ring_slot))
-                count = int(self._hist_count[rec.ring_slot])
+                    np.int32(row))
+                count = int(self._hist_count[row])
             with self.tracer.span("rollout"):
                 center, lo, hi, conf = self.scenario_runner.rollout(
                     theta_hist, count, y0, us_eff)
@@ -1130,8 +1059,6 @@ class TwinServer:
         """Current deadline-degradation ladder level (0 = full service)."""
         return self._degradation.level
 
-    _GUARD_KINDS = ("OK", "REFIT", "ALERT")
-
     def snapshot_state(self) -> dict:
         """Full serving state as a fixed-shape host pytree — what a
         `TwinCheckpointer` writes and `restore_state` consumes.
@@ -1149,27 +1076,6 @@ class TwinServer:
         telemetry journal's job) and the bounded debug/metric windows
         (registry children are restart-safe monotone counters).
         """
-        cap = self.cfg.max_twins
-        refit_slot = np.full((cap,), -1, np.int32)
-        deploy_tick = np.full((cap,), -1, np.int64)
-        admitted_tick = np.full((cap,), -1, np.int64)
-        steps_in_slot = np.zeros((cap,), np.int64)
-        guard_code = np.zeros((cap,), np.int8)
-        guard_live = np.zeros((cap,), bool)
-        kind_code = {k: i for i, k in enumerate(self._GUARD_KINDS)}
-        for rec in self.twin_snapshot().values():
-            row = rec.ring_slot
-            refit_slot[row] = -1 if rec.refit_slot is None else rec.refit_slot
-            deploy_tick[row] = rec.deploy_tick
-            admitted_tick[row] = rec.admitted_tick
-            steps_in_slot[row] = rec.steps_in_slot
-            guard_code[row] = kind_code[
-                self._guard_state.get(rec.twin_id, "OK")]
-        for row in self._guard_live:
-            guard_live[row] = True
-        slot_twin_ids = np.full((self.cfg.refit_slots,), -1, np.int64)
-        for slot, tid in self._slot_twin.items():
-            slot_twin_ids[slot] = tid
         return {
             "theta": self._theta,
             "theta_hist": self._theta_hist,
@@ -1178,14 +1084,9 @@ class TwinServer:
             "fstate": self._fstate,
             "key": self._key,
             "packed": self.packed.snapshot(),
-            "rows": {"refit_slot": refit_slot, "deploy_tick": deploy_tick,
-                     "admitted_tick": admitted_tick,
-                     "steps_in_slot": steps_in_slot,
-                     "guard_code": guard_code, "guard_live": guard_live},
             "slot_ring": self._slot_ring.copy(),
-            "slot_twin_ids": slot_twin_ids,
             "scalars": np.asarray(
-                [self.tick_count, self._n_deployed,
+                [self.tick_count,
                  0 if self._rotation is None else self._rotation._cursor,
                  -1 if self._max_active is None else self._max_active],
                 np.int64),
@@ -1194,11 +1095,8 @@ class TwinServer:
     def restore_state(self, state: dict) -> None:
         """Rebuild this server's serving state from a `snapshot_state`
         tree (typically `checkpoint.restore`d into a fresh server's own
-        snapshot as `like`).  In-place where aliasing matters: the packed
-        columns are loaded with `[:]` so `_div` keeps aliasing
-        `packed.divergence`.  The registry (TwinRecord dict, row maps,
-        guard-live set) is rebuilt from the packed columns + per-row extras.
-        Serving-thread only; call before any post-restart ingest/tick."""
+        snapshot as `like`).  Serving-thread only; call before any
+        post-restart ingest/tick."""
         self._theta = jnp.asarray(state["theta"])
         self._theta_hist = jnp.asarray(state["theta_hist"])
         self._hist_count[:] = np.asarray(state["hist_count"])
@@ -1207,48 +1105,41 @@ class TwinServer:
         self._key = jnp.asarray(state["key"])
         self.packed.load(state["packed"])
         self._slot_ring[:] = np.asarray(state["slot_ring"], np.int32)
-        scalars = np.asarray(state["scalars"])
-        self.tick_count = int(scalars[0])
-        self._n_deployed = int(scalars[1])
+        tick, cursor, max_active = np.asarray(state["scalars"]).tolist()
+        self.tick_count = tick
         if self._rotation is not None:
-            self._rotation._cursor = int(scalars[2])
-        ma = int(scalars[3])
-        self._max_active = None if ma < 0 else ma
-        rows = state["rows"]
-        refit_slot = np.asarray(rows["refit_slot"])
-        deploy_tick = np.asarray(rows["deploy_tick"])
-        admitted_tick = np.asarray(rows["admitted_tick"])
-        steps_in_slot = np.asarray(rows["steps_in_slot"])
-        guard_code = np.asarray(rows["guard_code"])
-        guard_live = np.asarray(rows["guard_live"])
-        p = self.packed
-        with self._reg_lock:
-            self.twins.clear()
-            self._row2rec.clear()
-            self._guard_state.clear()
-            self._guard_live.clear()
-            self._slot_twin.clear()
-            for row in np.flatnonzero(p.registered):
-                row = int(row)
-                rec = TwinRecord(
-                    twin_id=int(p.twin_id[row]), ring_slot=row,
-                    refit_slot=(None if refit_slot[row] < 0
-                                else int(refit_slot[row])),
-                    samples=int(p.samples[row]),
-                    samples_at_deploy=int(p.samples_at_deploy[row]),
-                    deployed=bool(p.deployed[row]),
-                    deploy_tick=int(deploy_tick[row]),
-                    admitted_tick=int(admitted_tick[row]),
-                    residency=int(p.residency[row]),
-                    steps_in_slot=int(steps_in_slot[row]),
-                    divergence=float(p.divergence[row]))
-                self.twins[rec.twin_id] = rec
-                self._row2rec[row] = rec
-                self._guard_state[rec.twin_id] = \
-                    self._GUARD_KINDS[int(guard_code[row])]
-                if guard_live[row]:
-                    self._guard_live[row] = rec
-            for slot, tid in enumerate(np.asarray(state["slot_twin_ids"])):
-                if tid >= 0:
-                    self._slot_twin[slot] = int(tid)
+            self._rotation._cursor = cursor
+        self._max_active = None if max_active < 0 else max_active
         self._live_dirty = True
+
+
+class _TwinView(Mapping):
+    """Read-only twin_id -> `TwinRecord` view of a `PackedFleet`: each read
+    builds a fresh record from the twin's row, so mutating one changes
+    nothing the server holds.  A lookup is O(1); iteration walks a copy of
+    the id map taken under the registration lock."""
+
+    def __init__(self, packed: PackedFleet):
+        self._p = packed
+
+    def __getitem__(self, twin_id: int) -> TwinRecord:
+        p = self._p
+        row = p.row_of[twin_id]
+        slot = int(p.refit_slot[row])
+        return TwinRecord(
+            twin_id=int(p.twin_id[row]), ring_slot=row,
+            refit_slot=None if slot < 0 else slot,
+            samples=int(p.samples[row]),
+            samples_at_deploy=int(p.samples_at_deploy[row]),
+            deployed=bool(p.deployed[row]),
+            deploy_tick=int(p.deploy_tick[row]),
+            admitted_tick=int(p.admitted_tick[row]),
+            residency=int(p.residency[row]),
+            steps_in_slot=int(p.steps_in_slot[row]),
+            divergence=float(p.divergence[row]))
+
+    def __iter__(self):
+        return iter(self._p.ids())
+
+    def __len__(self) -> int:
+        return len(self._p.row_of)

@@ -9,7 +9,7 @@ Three servers implement the same serving surface at three scales:
                           (twin/wire.py)
 
 The process split is what forces the protocol: a coordinator cannot reach
-into a worker's `TwinRecord` dict or theta store, so everything a caller may
+into a worker's packed twin state or theta store, so everything a caller may
 depend on has to be a method on this surface — and once it is, telemetry
 producers, front doors (`twin.wire.IngestFrontDoor`), benchmarks, and the
 conformance suite (tests/test_service_conformance.py) run unchanged against

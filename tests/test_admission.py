@@ -132,8 +132,10 @@ def test_admission_compiles_once_and_launches_only_when_admitting(world):
     free = iter(range(SLOTS))
     ticks = []
     for n in (1, 8, 3):
-        if n > SLOTS - len(srv._slot_twin):       # release the pool
-            srv._apply_plan(SchedulePlan(release=list(srv._slot_twin.values())))
+        residents = [int(srv.packed.twin_id[row]) for row in srv._slot_ring
+                     if row != srv._scratch]
+        if n > SLOTS - len(residents):            # release the pool
+            srv._apply_plan(SchedulePlan(release=residents))
             free = iter(range(SLOTS))
             tid = iter(range(TWINS))
         admit = [(next(free), next(tid)) for _ in range(n)]

@@ -289,24 +289,6 @@ def test_scenario_shrink_is_deterministic_across_shards(lv_world):
         srv.close()
 
 
-def test_federation_config_deprecated_kwargs():
-    """Satellite of the config consolidation: old `FederationConfig`
-    kwargs keep working for one release, warning, and route to the new
-    field names; mixing old and new spellings is an error."""
-    from repro.twin import FederationConfig
-
-    with pytest.warns(DeprecationWarning, match="min_slots"):
-        cfg = FederationConfig(8, min_slots=2)
-    assert cfg.min_shard_slots == 2
-    with pytest.warns(DeprecationWarning):
-        assert cfg.min_slots == 2          # deprecated read-alias
-    with pytest.warns(DeprecationWarning, match="smooth"):
-        cfg = FederationConfig(8, smooth=0.25)
-    assert cfg.pressure_smooth == 0.25
-    with pytest.raises(TypeError):
-        FederationConfig(8, min_shard_slots=1, min_slots=1)
-
-
 def test_conforms_reports_missing_surface():
     class Half:
         def ingest(self):

@@ -17,6 +17,7 @@ from repro.core.merinda import MerindaConfig
 from repro.systems.lotka_volterra import LotkaVolterra
 from repro.systems.simulate import simulate_batch
 from repro.twin.monitor import GuardConfig
+from repro.twin.packed import GUARD_KINDS
 from repro.twin.recovery import (ChaosConfig, ChaosInjector,
                                  DegradationConfig, DegradationPolicy,
                                  RecoveryConfig, TelemetryJournal,
@@ -193,8 +194,8 @@ def test_degradation_disabled_observes_but_never_sheds():
 # federation: dead shards give their slots to the survivors
 # --------------------------------------------------------------------- #
 def test_federation_dead_shard_grant_flows_to_survivors():
-    fed = SlotFederation(FederationConfig(total_slots=8, min_slots=1,
-                                          smooth=1.0), [4, 4, 4])
+    fed = SlotFederation(FederationConfig(total_slots=8, min_shard_slots=1,
+                                          pressure_smooth=1.0), [4, 4, 4])
     base = fed.rebalance([1.0, 1.0, 1.0])
     assert sum(base) == 8 and all(g >= 1 for g in base)
     dead = fed.rebalance([1.0, 0.0, 1.0], alive=[True, False, True])
@@ -206,8 +207,8 @@ def test_federation_dead_shard_grant_flows_to_survivors():
 
 
 def test_federation_all_dead_parks_the_budget():
-    fed = SlotFederation(FederationConfig(total_slots=6, min_slots=1,
-                                          smooth=1.0), [3, 3])
+    fed = SlotFederation(FederationConfig(total_slots=6, min_shard_slots=1,
+                                          pressure_smooth=1.0), [3, 3])
     assert fed.rebalance([1.0, 1.0], alive=[False, False]) == [0, 0]
 
 
@@ -320,8 +321,11 @@ def test_server_snapshot_restore_roundtrip(lv_world):
             assert (r2.samples, r2.deployed, r2.refit_slot, r2.residency) \
                 == (rec.samples, rec.deployed, rec.refit_slot, rec.residency)
             assert r2.divergence == pytest.approx(rec.divergence)
-        assert twin._guard_state == srv._guard_state
-        assert twin._slot_twin == srv._slot_twin
+        for col, want in srv.packed.snapshot().items():
+            np.testing.assert_array_equal(getattr(twin.packed, col), want,
+                                          err_msg=col)
+        assert twin.packed.row_of == srv.packed.row_of
+        np.testing.assert_array_equal(twin._slot_ring, srv._slot_ring)
         np.testing.assert_array_equal(np.asarray(twin._theta),
                                       np.asarray(srv._theta))
         np.testing.assert_array_equal(
@@ -336,6 +340,39 @@ def test_server_snapshot_restore_roundtrip(lv_world):
         assert [e.kind for e in r1.events] == [e.kind for e in r2.events]
     finally:
         srv.close()
+
+
+def test_restored_server_emits_events_in_live_order(lv_world):
+    """A restored server reports a tick's guard events in the order the
+    live server does, even when twins became guard-eligible out of ring-row
+    order."""
+    sys_, ys, _ = lv_world
+    cfg = _server_cfg(sys_, deploy_after=10 ** 6)    # guard only
+    srv = TwinServer(cfg)
+    twin = TwinServer(cfg, share_modules_from=srv)
+    try:
+        true = sys_.true_theta(srv.fleet.model.lib)
+        for t in range(2):
+            for i in range(2):
+                srv.ingest(i, ys[i, t * 20:(t + 1) * 20])
+            srv.tick()
+        srv.deploy(1, -true)                # twin 1 is guarded first
+        srv.deploy(0, -true)
+        twin.restore_state(
+            jax.tree.map(np.asarray, jax.device_get(srv.snapshot_state())))
+        both = False
+        for t in range(2, 6):
+            for i in range(2):
+                srv.ingest(i, ys[i, t * 20:(t + 1) * 20])
+                twin.ingest(i, ys[i, t * 20:(t + 1) * 20])
+            live, restored = srv.tick().events, twin.tick().events
+            assert ([(e.twin_id, e.kind) for e in restored]
+                    == [(e.twin_id, e.kind) for e in live])
+            both |= len({e.twin_id for e in live}) == 2
+        assert both                         # one tick flagged both twins
+    finally:
+        srv.close()
+        twin.close()
 
 
 def test_restore_rejects_mismatched_shapes(lv_world):
@@ -367,8 +404,9 @@ def _fleet_cfg(sys_, shards, twins_per_shard, **kw):
 
 
 def _alert_sets(fleet):
-    state = {tid for s in fleet.shards if s is not None
-             for tid, k in s._guard_state.items() if k == "ALERT"}
+    alert = GUARD_KINDS.index("ALERT")
+    state = {int(s.packed.twin_id[row]) for s in fleet.shards if s is not None
+             for row in np.flatnonzero(s.packed.guard_code == alert)}
     events = {e.twin_id for s in fleet.shards if s is not None
               for e in s.events if e.kind == "ALERT"}
     return state, events

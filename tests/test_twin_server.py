@@ -1,4 +1,7 @@
 """Online twin server: scheduling order, admit/evict, guard, predict."""
+import sys
+import threading
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -8,6 +11,7 @@ from repro.core.merinda import MerindaConfig
 from repro.systems.lotka_volterra import LotkaVolterra
 from repro.systems.simulate import simulate_batch
 from repro.twin.monitor import DivergenceGuard, GuardConfig
+from repro.twin.packed import PackedFleet
 from repro.twin.scheduler import (PackedRefitScheduler, RefitScheduler,
                                   SchedulerConfig, TwinRecord)
 from repro.twin.server import TwinServer, TwinServerConfig
@@ -175,11 +179,12 @@ def test_server_slot_turnover_rotates_fleet(lv_world):
 
 
 def test_packed_mirrors_track_records_through_serving(lv_world):
-    """The packed arrays are the scheduler's truth; every server mutation
-    point must keep them consistent with the record metadata AND keep the
-    float32 divergence shadow in lockstep with the float64 column — a
-    stale mirror silently mis-ranks candidates, which the from_records
-    equivalence tests can never see."""
+    """The packed columns are the server's only per-twin state; through
+    serving the device shadows (float32 divergence, `resident`) must stay
+    in lockstep with their host truth — a stale shadow silently mis-ranks
+    candidates, which the from_records equivalence tests can never see —
+    the `twins` view must read each row's columns, and the slot ring and
+    `refit_slot` must name the same residents."""
     sys_, ys, us = lv_world
     srv = _server(sys_, max_residency=3, min_residency=1)
     chunk = 10
@@ -190,15 +195,101 @@ def test_packed_mirrors_track_records_through_serving(lv_world):
         srv.tick()
         p = srv.packed
         p.check_mirrors()
-        for rec in srv.twins.values():
+        assert sorted(srv.twins) == [0, 1, 2, 3]
+        for tid, rec in srv.twins.items():
             row = rec.ring_slot
-            assert p.registered[row] and p.twin_id[row] == rec.twin_id
-            assert p.samples[row] == rec.samples
-            assert p.samples_at_deploy[row] == rec.samples_at_deploy
-            assert p.deployed[row] == rec.deployed
-            assert p.divergence[row] == rec.divergence
-            assert p.resident[row] == (rec.refit_slot is not None)
-            assert p.residency[row] == rec.residency
+            assert p.row_of[tid] == row and p.registered[row]
+            assert rec.twin_id == p.twin_id[row] == tid
+            assert rec.samples == p.samples[row]
+            assert rec.samples_at_deploy == p.samples_at_deploy[row]
+            assert rec.deployed == p.deployed[row]
+            assert rec.divergence == p.divergence[row]
+            assert rec.residency == p.residency[row]
+            assert rec.steps_in_slot == p.steps_in_slot[row]
+            assert rec.deploy_tick == p.deploy_tick[row]
+            assert rec.admitted_tick == p.admitted_tick[row]
+            slot = -1 if rec.refit_slot is None else rec.refit_slot
+            assert slot == p.refit_slot[row]
+            assert (slot >= 0) == (row in srv._slot_ring)
+        for slot, row in enumerate(srv._slot_ring):
+            if row != srv._scratch:
+                assert p.refit_slot[row] == slot
+
+
+def test_packed_register_is_thread_safe():
+    """Ingest threads register twins while the serving side iterates the
+    ids: every id gets exactly one row, the rows are dense, and the id map
+    agrees with the `twin_id` column."""
+    fleet = PackedFleet(1024)
+    errors = []
+
+    def guarded(fn, *args):
+        try:
+            fn(*args)
+        except Exception as e:      # reported below, not lost in a thread
+            errors.append(e)
+
+    def register(k):              # every id, in an order of its own
+        for tid in np.random.default_rng(k).permutation(1000).tolist():
+            fleet.register(tid)
+
+    def iterate():
+        for _ in range(300):
+            for tid in fleet.ids():
+                fleet.row_of[tid]
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = ([threading.Thread(target=guarded, args=(register, k))
+                    for k in range(16)]
+                   + [threading.Thread(target=guarded, args=(iterate,))
+                      for _ in range(2)])
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors
+    assert sorted(fleet.row_of) == list(range(1000))
+    assert sorted(fleet.row_of.values()) == list(range(1000))
+    for tid, row in fleet.row_of.items():
+        assert fleet.twin_id[row] == tid and fleet.registered[row]
+    assert int(fleet.registered.sum()) == 1000
+
+
+def test_twins_view_is_a_copy(lv_world):
+    """A record read from `srv.twins` is a copy: mutating it changes
+    neither the server's snapshot nor its next plan."""
+    sys_, ys, us = lv_world
+    ctl = _server(sys_)
+    srv = TwinServer(ctl.cfg, share_modules_from=ctl)
+
+    def feed(t):
+        for s in (ctl, srv):
+            for i in range(4):
+                s.ingest(i, ys[i, t * 10:(t + 1) * 10],
+                         us[i, t * 10:(t + 1) * 10])
+
+    for t in range(4):              # 40 samples each: one short of ready
+        feed(t)
+        ctl.tick()
+        srv.tick()
+    before = srv.snapshot_state()
+    for tid in (2, 3):
+        rec = srv.twins[tid]
+        rec.samples += 1000         # would outrank twins 0 and 1
+        rec.divergence = 50.0
+        rec.refit_slot = 0
+        rec.steps_in_slot = rec.deploy_tick = 99
+    jax.tree.map(np.testing.assert_array_equal, srv.snapshot_state(), before)
+    assert srv.twins[2].samples == 40 and srv.twins[3].refit_slot is None
+    feed(4)
+    want, got = ctl.tick(), srv.tick()
+    assert want.admitted == [(0, 0), (1, 1)]
+    assert got.admitted == want.admitted
 
 
 def test_guard_fires_on_perturbed_dynamics(lv_world):

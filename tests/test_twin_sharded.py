@@ -141,8 +141,8 @@ def test_plan_sheds_lowest_priority_when_grant_shrinks():
 
 
 def test_federation_moves_slots_toward_pressure():
-    fed = SlotFederation(FederationConfig(total_slots=6, min_slots=1,
-                                          smooth=1.0), [4, 4])
+    fed = SlotFederation(FederationConfig(total_slots=6, min_shard_slots=1,
+                                          pressure_smooth=1.0), [4, 4])
     assert fed.rebalance([1.0, 1.0]) == [3, 3]          # symmetric demand
     grants = fed.rebalance([0.1, 10.0])
     assert grants[1] > grants[0] and sum(grants) == 6
@@ -150,8 +150,8 @@ def test_federation_moves_slots_toward_pressure():
 
 
 def test_federation_floor_keeps_idle_shard_alive():
-    fed = SlotFederation(FederationConfig(total_slots=4, min_slots=1,
-                                          smooth=1.0), [4, 4])
+    fed = SlotFederation(FederationConfig(total_slots=4, min_shard_slots=1,
+                                          pressure_smooth=1.0), [4, 4])
     assert fed.rebalance([0.0, 50.0]) == [1, 3]
 
 
