@@ -208,9 +208,8 @@ class PackedFleet:
 # --------------------------------------------------------------------------- #
 # the fused scoring kernel: one jit-compiled call over the whole fleet
 # --------------------------------------------------------------------------- #
-@partial(jax.jit, static_argnames=("k",))
-def _fleet_scores(samples, at_deploy, deployed, divergence, resident,
-                  registered, min_samples, sw, dw, k: int):
+def _score_terms(samples, at_deploy, deployed, divergence, resident,
+                 registered, min_samples, sw, dw, k: int):
     """Score every row and reduce to what the host actually needs.
 
         priority = sw * (staleness + never_deployed) + dw * divergence
@@ -237,6 +236,18 @@ def _fleet_scores(samples, at_deploy, deployed, divergence, resident,
     return cand_rows, cand_prio, n_waiting, pressure
 
 
+@partial(jax.jit, static_argnames=("k",))
+def _fleet_scores(*operands, k: int):
+    """`_score_terms` packed into one int32 [2k + 2] array, so the host reads
+    it back once: cand_rows, then cand_prio and pressure bit-cast from
+    float32 (bit-exact, the -inf padding included), then n_waiting."""
+    cand_rows, cand_prio, n_waiting, pressure = _score_terms(*operands, k=k)
+    bits = jax.lax.bitcast_convert_type
+    return jnp.concatenate([
+        cand_rows.astype(jnp.int32), bits(cand_prio, jnp.int32),
+        bits(pressure, jnp.int32)[None], n_waiting.astype(jnp.int32)[None]])
+
+
 def _device_operands(fleet: PackedFleet):
     # zero-copy: every column is already in the kernel's dtype (int32
     # counters, float32 divergence shadow) — no O(n) conversion pass on the
@@ -249,21 +260,17 @@ def fleet_scores(fleet: PackedFleet, *, min_samples: int, sw: float,
                  dw: float, k: int, span=null_span):
     """Host wrapper: returns (cand_rows, cand_prio, n_waiting, pressure)
     as numpy/python values.  Rows whose cand_prio is -inf are padding
-    (fewer than k twins waiting) — callers must drop them.  Each of the
-    four reads back is a `sync` span of `span` (a `Tracer.span`)."""
+    (fewer than k twins waiting) — callers must drop them.  The one read
+    back of the packed result is a `sync` span of `span` (a
+    `Tracer.span`)."""
     k = max(1, min(k, fleet.capacity))
-    cand_rows, cand_prio, n_waiting, pressure = _fleet_scores(
+    packed = _fleet_scores(
         *_device_operands(fleet), np.int32(min_samples), np.float32(sw),
-        np.float32(dw), k)
-    with span("sync", site="plan.rows"):
-        cand_rows = np.asarray(cand_rows)
-    with span("sync", site="plan.prio"):
-        cand_prio = np.asarray(cand_prio)
-    with span("sync", site="plan.waiting"):
-        n_waiting = int(n_waiting)
-    with span("sync", site="plan.pressure"):
-        pressure = float(pressure)
-    return cand_rows, cand_prio, n_waiting, pressure
+        np.float32(dw), k=k)
+    with span("sync", site="plan.scores"):
+        packed = np.asarray(packed)
+    floats = packed[k:2 * k + 1].view(np.float32)
+    return (packed[:k], floats[:k], int(packed[-1]), float(floats[k]))
 
 
 @jax.jit

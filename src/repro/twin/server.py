@@ -763,20 +763,23 @@ class TwinServer:
             for _ in range(self.cfg.steps_per_tick):
                 self._fstate, loss_vec, _ = self.fleet.train_step_per_slot(
                     self._fstate, y_win, u_win)
-            with self.tracer.span("sync", site="refit.loss"):
-                loss_vec = np.asarray(loss_vec)
-            # report loss over ASSIGNED slots only — scratch-parked slots
-            # train on zero windows and would dilute the mean toward zero
-            loss = float(np.mean(loss_vec[slots]))
-        p.steps_in_slot[rows] += self.cfg.steps_per_tick
-        p.residency[rows] += 1
-        deployable = slots[p.steps_in_slot[rows] >= self.cfg.deploy_after]
-        if deployable.size and not skip_promote:
+            p.steps_in_slot[rows] += self.cfg.steps_per_tick
+            p.residency[rows] += 1
+            deployable = slots[p.steps_in_slot[rows] >= self.cfg.deploy_after]
+            promote = deployable.size > 0 and not skip_promote
+            if not promote:
+                with self.tracer.span("sync", site="refit.loss"):
+                    loss_vec = np.asarray(loss_vec)
+        if promote:
+            # the loss steers nothing before promotion's own read: read both
+            # at once, after promotion's programs are dispatched
             with self.tracer.span("promote"):
-                self._promote(deployable, y_win, u_win)
-        return loss
+                loss_vec = self._promote(deployable, y_win, u_win, loss_vec)
+        # report loss over ASSIGNED slots only — scratch-parked slots
+        # train on zero windows and would dilute the mean toward zero
+        return float(np.mean(loss_vec[slots]))
 
-    def _promote(self, deployable, y_win, u_win) -> None:
+    def _promote(self, deployable, y_win, u_win, loss_vec=None):
         """Shadow-evaluate slot recoveries and deploy only improvements.
 
         Both the candidate theta and the incumbent are rolled over the same
@@ -785,6 +788,10 @@ class TwinServer:
         `promote_margin` — "good enough" is not enough to replace a model
         that tracks reality better.  Against a missing/diverged incumbent the
         candidate ships if it is outright good or a margin improvement.
+
+        `loss_vec`, the train steps' per-slot loss still on the device (None
+        on the degraded path, which trains nothing), is read back together
+        with both scores in one `sync` and returned as numpy.
         """
         thresh = self.cfg.guard.refit_threshold
         rows = jnp.asarray(self._slot_ring)
@@ -792,11 +799,9 @@ class TwinServer:
         ys_g, us_g = self.ring.latest(self._rstate, rows,
                                       self.cfg.guard.window)
         cand = self.guard.score(thetas, ys_g, us_g)
-        with self.tracer.span("sync", site="promote.cand"):
-            cand = np.asarray(cand)
         inc = self.guard.score(self._theta[rows], ys_g, us_g)
-        with self.tracer.span("sync", site="promote.inc"):
-            inc = np.asarray(inc)
+        with self.tracer.span("sync", site="refit.result"):
+            loss_vec, cand, inc = jax.device_get((loss_vec, cand, inc))
         p = self.packed
         drows = self._slot_ring[deployable]
         cand_d, inc_d = cand[deployable], inc[deployable]
@@ -817,6 +822,7 @@ class TwinServer:
             self._hist_push(prows, thetas[jnp.asarray(slots)])
             p.set_divergence(prows, np.minimum(cand_d[won], 1e6))
             self._mark_deployed(prows)
+        return loss_vec
 
     # ------------------------------------------------------------------ #
     def tick(self) -> TickReport:

@@ -22,8 +22,12 @@ contract for why near-ties are the one tolerated divergence in production).
 """
 import random
 
+import jax
+import numpy as np
 import pytest
 
+from repro.twin.packed import (PackedFleet, _device_operands, _score_terms,
+                               fleet_scores)
 from repro.twin.scheduler import (PackedRefitScheduler, PriorityBuckets,
                                   RefitScheduler, SchedulerConfig, TwinRecord)
 
@@ -175,3 +179,56 @@ else:
     @pytest.mark.skip(reason="hypothesis not installed")
     def test_property_plans_identical():
         pass
+
+
+# --------------------------------------------------------------------- #
+# the fused scoring call's packed read back
+# --------------------------------------------------------------------- #
+def _random_packed(rng, capacity, n_waiting_max):
+    """A random PackedFleet of `capacity` rows with at most `n_waiting_max`
+    ready, unslotted rows (float divergences, not only exact ones)."""
+    fleet = PackedFleet(capacity)
+    n = int(rng.integers(1, capacity + 1))
+    fleet.registered[:n] = True
+    fleet.twin_id[:n] = np.arange(n)
+    fleet.samples[:n] = rng.integers(0, 64, n)
+    fleet.samples_at_deploy[:n] = rng.integers(0, fleet.samples[:n] + 1)
+    fleet.deployed[:n] = rng.random(n) < 0.5
+    fleet.set_divergence(slice(0, n), rng.random(n) * 3.0)
+    ready = np.flatnonzero(fleet.registered & (fleet.samples >= 4))
+    waiting = rng.permutation(ready)[:n_waiting_max]
+    fleet.resident[ready] = True
+    fleet.resident[waiting] = False
+    return fleet
+
+
+@pytest.mark.parametrize("capacity", [64, 4096])
+def test_packed_fleet_scores_bit_identical_to_unpacked(capacity):
+    """`fleet_scores` reads one packed int32 array; unpacked on the host it
+    must equal, bit for bit, the four results of the same jnp expressions
+    evaluated unpacked: rows, priorities (the -inf padding included),
+    waiting depth and pressure.  Fleets with fewer waiting rows than k
+    exercise the padding."""
+    unpacked = jax.jit(_score_terms, static_argnames=("k",))
+    rng = np.random.default_rng(capacity)
+    for _ in range(12):
+        k = int(rng.choice([1, 8, 64]))
+        n_waiting_max = int(rng.choice([0, 1, k // 2, k, 4 * k, capacity]))
+        fleet = _random_packed(rng, capacity, n_waiting_max)
+        min_samples, sw, dw = 4, float(rng.choice(WEIGHTS)), float(
+            rng.random() * 4)
+        rows, prio, n_waiting, pressure = fleet_scores(
+            fleet, min_samples=min_samples, sw=sw, dw=dw, k=k)
+        want = jax.device_get(unpacked(
+            *_device_operands(fleet), np.int32(min_samples), np.float32(sw),
+            np.float32(dw), k=min(k, capacity)))
+        np.testing.assert_array_equal(rows, want[0])
+        assert prio.dtype == np.float32
+        np.testing.assert_array_equal(prio.view(np.int32),
+                                      want[1].view(np.int32))
+        assert n_waiting == int(want[2])
+        assert np.float32(pressure).view(np.int32) == \
+            np.asarray(want[3], np.float32).view(np.int32)
+        n_real = min(k, n_waiting)
+        assert np.isneginf(prio[n_real:]).all()
+        assert np.isfinite(prio[:n_real]).all()

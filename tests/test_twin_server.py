@@ -162,6 +162,57 @@ def test_server_admits_and_refits(lv_world):
     assert int(srv._fstate["steps"].max()) > 0
 
 
+def test_tick_loss_is_mean_of_last_train_loss_over_assigned_slots(lv_world):
+    """`TickReport.loss` is the mean, over the slots holding a twin, of the
+    last train step's per-slot loss — whether the tick promotes (the loss
+    is then read back with the promotion scores) or not — and None on the
+    degraded paths that train nothing (level 2 defers the steps but may
+    still promote, level 3 skips promotion too)."""
+    sys_, ys, us = lv_world
+    srv = _server(sys_)
+    fleet = srv.fleet
+    step, recover = fleet.train_step_per_slot, fleet.recover_all
+    last = {}
+
+    def train_step_per_slot(state, y_win, u_win):
+        out = step(state, y_win, u_win)
+        last["loss"] = out[1]
+        return out
+
+    def recover_all(*args):
+        last["promoted"] = True
+        return recover(*args)
+
+    fleet.train_step_per_slot = train_step_per_slot
+    fleet.recover_all = recover_all
+    levels = [0] * 8 + [2, 2, 3, 3, 0, 0, 2, 0, 0, 3, 0, 0]
+    seen = set()
+    chunk = 10
+    for t, level in enumerate(levels):
+        for i in range(4):
+            srv.ingest(i, ys[i, t * chunk:(t + 1) * chunk],
+                       us[i, t * chunk:(t + 1) * chunk])
+        srv._degradation.level = level
+        last.clear()
+        report = srv.tick()
+        promoted = last.get("promoted", False)
+        if level >= 2 or "loss" not in last:
+            assert "loss" not in last and report.loss is None
+            if level == 3:
+                assert not promoted
+            seen.add(("deferred", promoted))
+            continue
+        slots = srv._occupied_slots()
+        assert slots.size
+        want = float(np.mean(np.asarray(last["loss"])[slots]))
+        assert report.loss == want
+        seen.add(("trained", promoted))
+    # every path ran: train with and without promotion, a deferred
+    # promotion (no loss to read) and a deferred tick that promotes nothing
+    assert seen == {("trained", True), ("trained", False),
+                    ("deferred", True), ("deferred", False)}
+
+
 def test_server_slot_turnover_rotates_fleet(lv_world):
     """With 4 ready twins and 2 slots, releases/evictions must rotate the
     pool: every twin gets slot time eventually."""
