@@ -2,10 +2,12 @@
 
 Everything a cell needs is found by name: `BENCHMARK.json` names the cell's
 configuration and traffic mix, `bench/configs/<config>.json` and
-`bench/traffic/<mix>.json` hold their parameters, `bench/limits/<cell>.json`
-the limits of the correctness comparison, `bench/e2e/<metric>.py` and
-`bench/metrics/<metric>.py` the readers of each metric.  Adding a cell, a
-configuration, a mix or a metric adds files and entries and edits none.
+`bench/traffic/<mix>.json` hold their parameters, the configuration's
+`system` names its physics family in `bench/physics/<system>.json`,
+`bench/limits/<cell>.json` the limits of the correctness comparison,
+`bench/e2e/<metric>.py` and `bench/metrics/<metric>.py` the readers of each
+metric.  Adding a cell, a configuration, a family, a mix or a metric adds
+files and entries and edits none.
 
 The loop is closed: every twin reports one chunk of telemetry through one
 `ingest_many`, then the server ticks once, then the mix's what-if queries
@@ -34,6 +36,8 @@ from pathlib import Path
 
 import numpy as np
 
+from bench.family import Physics, load_physics, theta
+
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
 
@@ -56,6 +60,26 @@ def load_module(path: Path):
     return mod
 
 
+def physics_for(cfg: dict, path: Path | None = None) -> Physics:
+    """The physics family a configuration names by its `system` key (from
+    `path` where given, else `bench/physics/<system>.json`), checked
+    against the library and the sampling interval the configuration
+    learns."""
+    path = path or BENCH / "physics" / f"{cfg['system']}.json"
+    phys = load_physics(path)
+    mc = cfg["merinda"]
+    if phys.name != cfg["system"]:
+        raise ValueError(f"{path} holds {phys.name!r}, the configuration "
+                         f"names {cfg['system']!r}")
+    if mc["dt"] != phys.dt:
+        raise ValueError(f"the configuration samples at merinda.dt "
+                         f"{mc['dt']}, {phys.name} at dt {phys.dt}")
+    if mc["order"] < phys.order:
+        raise ValueError(f"the configuration learns a library of order "
+                         f"{mc['order']}, below {phys.name}'s {phys.order}")
+    return phys
+
+
 @dataclass
 class Cell:
     name: str
@@ -65,6 +89,14 @@ class Cell:
     limits: dict
     end_to_end: list
     per_layer: list
+    physics: Physics             # the family config["system"] names
+
+    @property
+    def dims(self) -> dict:
+        """The learned library's order and the state and input counts, as
+        `bench/flops.py` takes them."""
+        return {"order": self.config["merinda"]["order"],
+                "n": self.physics.n, "m": self.physics.m}
 
     @staticmethod
     def load(name: str, bench_file: Path = ROOT / "BENCHMARK.json") -> "Cell":
@@ -78,9 +110,10 @@ class Cell:
 
         def serves(metric):
             return name in metric.get("workloads", [name])
+        config = load_json(ROOT / cfg_entry["file"])
         return Cell(
-            name=name, chips=int(w["chips"]),
-            config=load_json(ROOT / cfg_entry["file"]),
+            name=name, chips=int(w["chips"]), config=config,
+            physics=physics_for(config),
             traffic=load_json(BENCH / "traffic" / f"{w['traffic']}.json"),
             limits=load_json(BENCH / "limits" / f"{name}.json"),
             end_to_end=[m for m in spec["end_to_end"] if serves(m)],
@@ -109,14 +142,14 @@ def peak_of(kind: str) -> dict:
 
 
 # --------------------------------------------------------------------------- #
-def build_server(cfg: dict, tracer=None):
-    """The program's server for a configuration file."""
+def build_server(cfg: dict, physics: Physics, tracer=None):
+    """The program's server for a configuration file and its physics."""
     from repro.core.merinda import MerindaConfig
     from repro.twin.monitor import GuardConfig
     from repro.twin.server import TwinServer, TwinServerConfig
     from repro.twin.sharded import ShardedTwinConfig, ShardedTwinServer
 
-    merinda = MerindaConfig(n=3, m=1, **cfg["merinda"])
+    merinda = MerindaConfig(n=physics.n, m=physics.m, **cfg["merinda"])
     fields = dict(cfg["server_config"])
     fields.setdefault("max_twins", cfg["twins"])
     scfg = TwinServerConfig(merinda=merinda, guard=GuardConfig(**cfg["guard"]),
@@ -139,8 +172,8 @@ def shard_servers(srv) -> list:
 @dataclass
 class Traffic:
     """The cell's telemetry and queries, made from the seed before the window."""
-    ys: np.ndarray               # [N, T, 3] noisy states, float32
-    us: np.ndarray               # [N, T, 1] elevator, float32
+    ys: np.ndarray               # [N, T, n] noisy states, float32
+    us: np.ndarray               # [N, T, m] inputs, float32
     chunk: int
     q_twins: np.ndarray          # [max_loops, Q] queried twin per slot
     q_seeds: np.ndarray          # [max_loops] per-loop query input seeds
@@ -159,11 +192,13 @@ class Traffic:
     def query_inputs(self, loop: int, k: int, horizon: int, std: float):
         rng = np.random.default_rng(int(self.q_seeds[loop]))
         q = self.q_twins.shape[1]
-        return (std * rng.standard_normal((q, k, horizon, 1))
+        return (std * rng.standard_normal((q, k, horizon,
+                                           self.us.shape[-1]))
                 ).astype(np.float32)
 
 
-def make_traffic(cfg: dict, mix: dict, seed: int, seconds: float) -> Traffic:
+def make_traffic(cfg: dict, physics: Physics, mix: dict, seed: int,
+                 seconds: float) -> Traffic:
     from bench import telemetry
 
     n, chunk = cfg["twins"], cfg["chunk"]
@@ -181,12 +216,13 @@ def make_traffic(cfg: dict, mix: dict, seed: int, seconds: float) -> Traffic:
                   + np.linspace(lo, hi, n_dmg).round().astype(np.int64)) \
         * chunk
     ys, us = telemetry.stream(
-        int(rng.integers(0, 2 ** 63)), n, T, y0_frac=mix["y0_frac"],
+        physics, int(rng.integers(0, 2 ** 63)), n, T, y0_frac=mix["y0_frac"],
         input_scale=mix["input_scale"], noise_std=mix["noise_std"],
         onset=onset, effectiveness=mix["damage_effectiveness"],
         gains=mix["sas_gains"])
     if not np.all(np.isfinite(ys)):
-        raise RuntimeError("telemetry left the F-8's controlled flight")
+        raise RuntimeError(f"the {physics.name} telemetry is not finite: "
+                           "the mix leaves the model's stable region")
     q = int(mix.get("queries_per_loop", 0))
     if q:
         ranks = np.arange(1, n + 1, dtype=np.float64) ** -mix["query_zipf_s"]
@@ -448,17 +484,17 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     cfg, mix = cell.config, cell.traffic
     phases = {"start": time.perf_counter() - t_start}
     t0 = time.perf_counter()
-    traffic = make_traffic(cfg, mix, seed, seconds)
+    traffic = make_traffic(cfg, cell.physics, mix, seed, seconds)
     phases["telemetry"] = time.perf_counter() - t0
     t0 = time.perf_counter()
     tracer = Tracer(capacity=1 << 20) if trace else None
-    srv = build_server(cfg, tracer=tracer)
+    srv = build_server(cfg, cell.physics, tracer=tracer)
     rec = Recorder(srv)
     annotate = jax.profiler.TraceAnnotation
     try:
-        # ---- set-up: warm start every twin with the nominal F-8 model ----
-        from bench import telemetry
-        theta0 = telemetry.theta_f8().astype(np.float32)
+        # ---- set-up: warm start every twin with the family's nominal model
+        theta0 = theta(cell.physics, cfg["merinda"]["order"]).astype(
+            np.float32)
         srv.deploy_many(list(range(cfg["twins"])),
                         np.broadcast_to(theta0, (cfg["twins"],)
                                         + theta0.shape))

@@ -5,13 +5,15 @@ from bench import flops
 
 
 def read(ctx):
-    m = ctx.cell.config["merinda"]
+    m, d = ctx.cell.config["merinda"], ctx.cell.dims
     seconds = sum(ctx.traced.get("tick_s") or [])
-    f = sum(flops.rk4_flops(B, T) for B, T in ctx.traced_calls("rk4_guard"))
+    f = sum(flops.rk4_flops(B, T, **d)
+            for B, T in ctx.traced_calls("rk4_guard"))
     f += sum(3 * flops.merinda_forward_flops(F * S, k, m["hidden"],
-                                             m["head_hidden"])
+                                             m["head_hidden"], **d)
              for F, S, k in ctx.traced_calls("refit_step"))
-    f += sum(flops.encode_flops(F * S, k, m["hidden"], m["head_hidden"])
+    f += sum(flops.encode_flops(F * S, k, m["hidden"], m["head_hidden"],
+                                **d)
              for F, S, k in ctx.traced_calls("recover"))
     if seconds <= 0 or f <= 0:
         return None

@@ -4,8 +4,9 @@ Straightforward `jax.numpy` in float32, written from the published
 description of each stage and from the configuration file, importing
 nothing of the program:
 
-  * the F-8 library and a classic RK4 rollout of dY/dt = theta @ Phi(Y, u)
-    with zero-order-hold inputs;
+  * the cell's library (every monomial of degree <= the configuration's
+    `merinda.order` over the family's n states and m inputs) and a classic
+    RK4 rollout of dY/dt = theta @ Phi(Y, u) with zero-order-hold inputs;
   * the guard score: mean squared rollout error over the window, divided by
     the window's variance plus 1e-6, non-finite scores clamped to 1e6;
   * the MERINDA refit step: a GRU encoder over the normalised windows, a
@@ -33,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.telemetry import monomials
+from bench.family import monomials
 
 _BLOWUP = 1e6
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -59,18 +60,19 @@ def mm(spec: str, a, b, mode: str):
     return e(ah, bh) + e(ah, bl) + e(al, bh)
 
 
-def features(y, u):
-    """Library Phi [..., 35] of y [..., 3], u [..., 1]."""
+def features(y, u, order: int):
+    """Library Phi [..., L] of y [..., n], u [..., m]: every monomial of
+    degree <= `order`."""
     x = jnp.concatenate([y, u], axis=-1)
     one = jnp.ones(x.shape[:-1], x.dtype)
     return jnp.stack([jnp.prod(x[..., list(t)], axis=-1) if t else one
-                      for t in monomials()], axis=-1)
+                      for t in monomials(x.shape[-1], order)], axis=-1)
 
 
-def rk4(theta, y0, us, dt: float, mode: str):
-    """theta [B, 3, L], y0 [B, 3], us [B, T, 1] -> ys [B, T+1, 3]."""
+def rk4(theta, y0, us, dt: float, order: int, mode: str):
+    """theta [B, n, L], y0 [B, n], us [B, T, m] -> ys [B, T+1, n]."""
     def rhs(y, u):
-        return mm("bnl,bl->bn", theta, features(y, u), mode)
+        return mm("bnl,bl->bn", theta, features(y, u, order), mode)
 
     def step(y, u):
         k1 = rhs(y, u)
@@ -84,9 +86,9 @@ def rk4(theta, y0, us, dt: float, mode: str):
     return jnp.concatenate([y0[:, None], jnp.swapaxes(ys, 0, 1)], axis=1)
 
 
-@partial(jax.jit, static_argnames=("dt", "mode"))
-def guard_scores(theta, ys, us, *, dt: float, mode: str):
-    y_est = rk4(theta, ys[:, 0], us, dt, mode)
+@partial(jax.jit, static_argnames=("dt", "order", "mode"))
+def guard_scores(theta, ys, us, *, dt: float, order: int, mode: str):
+    y_est = rk4(theta, ys[:, 0], us, dt, order, mode)
     num = jnp.mean(jnp.square(y_est - ys), axis=(1, 2))
     den = jnp.mean(jnp.square(ys - jnp.mean(ys, axis=1, keepdims=True)),
                    axis=(1, 2)) + 1e-6
@@ -115,7 +117,7 @@ def _gru(xs, p, mode):
 
 def _encode(p, y_win, u_win, mcfg, mode):
     n, m = y_win.shape[-1], u_win.shape[-1]
-    L = len(monomials())
+    L = len(monomials(n + m, mcfg["order"]))
     norm = jax.lax.stop_gradient(p["norm"])
     xs = jnp.concatenate([y_win[:, :-1], u_win], axis=-1)
     xs = (xs - norm["mu"]) / norm["sigma"]
@@ -139,19 +141,20 @@ def _mask_top(theta_dense, phi_scale, n_active):
 
 
 def _loss(p, y_win, u_win, sparsify, mcfg, mode):
-    dt = mcfg["dt"]
+    dt, order = mcfg["dt"], mcfg["order"]
     theta_dense, shift = _encode(p, y_win, u_win, mcfg, mode)
     phi_scale = jax.lax.stop_gradient(p["norm"]["phi_scale"])
     theta = jnp.where(sparsify,
                       _mask_top(theta_dense, p["norm"]["phi_scale"],
                                 mcfg["n_active"]), theta_dense)
-    y_est = rk4(theta, y_win[:, 0], u_win + shift[:, None, :], dt, mode)
+    y_est = rk4(theta, y_win[:, 0], u_win + shift[:, None, :], dt, order,
+                mode)
     loss = jnp.mean(jnp.square(y_est - y_win))
     l1 = jnp.mean(jnp.abs(theta_dense * phi_scale))
     loss = loss + jnp.where(sparsify, 0.1 * mcfg["l1"], mcfg["l1"]) * l1
     dy = (y_win[:, 2:] - y_win[:, :-2]) / (2.0 * dt)
     pred = mm("snl,skl->skn", theta,
-              features(y_win[:, 1:-1], u_win[:, 1:]), mode)
+              features(y_win[:, 1:-1], u_win[:, 1:], order), mode)
     return loss + mcfg["collocation_weight"] * jnp.mean(jnp.square(pred - dy))
 
 
@@ -187,8 +190,8 @@ def refit_step(params, opt, steps, y_win, u_win, *, mode, mkey, rkey):
     return new, loss, g
 
 
-@partial(jax.jit, static_argnames=("dt", "mode"))
-def scenario(theta_hist, count, y0, us, *, dt: float, mode: str):
+@partial(jax.jit, static_argnames=("dt", "order", "mode"))
+def scenario(theta_hist, count, y0, us, *, dt: float, order: int, mode: str):
     """(centre [K, H+1, n], low, high, confidence [K], peak [K], growth
     [K]): `peak` is the largest state any ensemble member reaches under
     each input sequence, before the clamp (not finite where a rollout
@@ -206,7 +209,7 @@ def scenario(theta_hist, count, y0, us, *, dt: float, mode: str):
     y0s = jnp.stack([y0, y0 + nudge * (-1.0) ** jnp.arange(n)])
     both = rk4(jnp.concatenate([theta, theta]),
                jnp.repeat(y0s, E * K, axis=0), jnp.concatenate([u, u]),
-               dt, mode).reshape(2, E, K, H + 1, n)
+               dt, order, mode).reshape(2, E, K, H + 1, n)
     ys = both[0]
     peak = jnp.max(jnp.abs(ys), axis=(0, 2, 3))
     growth = jnp.max(jnp.abs(both[1] - ys), axis=(0, 2, 3)) / nudge
@@ -297,13 +300,14 @@ def check(cell, traffic, srv, rec, *, stand_in: str | None = None,
     mc = dict(cfg["merinda"])
     sc = cfg["server_config"]
     rc = cfg["refit"]
-    mkey = tuple(sorted({"dt": mc["dt"], "n_active": mc["n_active"],
+    mkey = tuple(sorted({"dt": mc["dt"], "order": mc["order"],
+                         "n_active": mc["n_active"],
                          "l1": mc["l1"], "theta_scale": mc["theta_scale"],
                          "collocation_weight": mc["collocation_weight"]
                          }.items()))
     rkey = tuple(sorted({"sparsify_after": sc["sparsify_after"],
                          "lr": sc["lr"], **rc}.items()))
-    dt = mc["dt"]
+    lib = {"dt": mc["dt"], "order": mc["order"]}
     high = stand_in == "high"
     scratch = sc.get("max_twins", cfg["twins"])
     win = Windows(traffic, srv)
@@ -328,9 +332,9 @@ def check(cell, traffic, srv, rec, *, stand_in: str | None = None,
             gaps["ring"].append(float(max(np.max(np.abs(ys_p - ys_b)),
                                           np.max(np.abs(us_p - us_b)))))
             theta = jnp.asarray(np.asarray(k["theta"])[idx])
-            ref = np.asarray(guard_scores(theta, ys_b, us_b, dt=dt,
+            ref = np.asarray(guard_scores(theta, ys_b, us_b, **lib,
                                           mode="highest"))
-            got = (np.asarray(guard_scores(theta, ys_b, us_b, dt=dt,
+            got = (np.asarray(guard_scores(theta, ys_b, us_b, **lib,
                                            mode="high")) if high
                    else np.asarray(k["out"])[idx])
             # a score over the alert threshold (1: the rollout's error
@@ -412,8 +416,8 @@ def check(cell, traffic, srv, rec, *, stand_in: str | None = None,
             args = (jnp.asarray(k["theta_hist"]), jnp.int32(k["count"]),
                     jnp.asarray(y0_b), jnp.asarray(k["us"]))
             *ref, peak, growth = [np.asarray(x) for x in scenario(
-                *args, dt=dt, mode="highest")]
-            got = ([np.asarray(x) for x in scenario(*args, dt=dt,
+                *args, **lib, mode="highest")]
+            got = ([np.asarray(x) for x in scenario(*args, **lib,
                                                     mode="high")]
                    if high else [np.asarray(x) for x in k["out"]])
             # an input sequence under which some member of the ensemble

@@ -6,12 +6,15 @@ path of the kernels), with the cell's own limits.  The control, the
 reference at 'high' (three bfloat16 passes) in the program's place, and each
 fault the cells can have must fail at least one compared number; the sound
 program must pass them all.  One chip has no exchange between chips to leave
-out.
+out.  A second family, the forced Van der Pol oscillator of the physics file
+beside this test, runs through the same harness with no edit to it.
 """
+from pathlib import Path
+
 import numpy as np
 import pytest
 
-from bench.harness import Cell, run_cell
+from bench.harness import Cell, physics_for, run_cell
 from repro.core.fleet import FleetMerinda
 from repro.twin.monitor import DivergenceGuard
 from repro.twin.scenario import ScenarioRunner
@@ -29,8 +32,21 @@ def small(name: str) -> Cell:
     return cell
 
 
-def run(name: str, **kw) -> dict:
-    return run_cell(small(name), SEED, 1.5, False, require_chip=False,
+def van_der_pol() -> Cell:
+    """`online64.steady` with the Van der Pol oscillator in the F-8's place:
+    its physics, its sampling interval and a gain for each of its states."""
+    cell = small("online64.steady")
+    cell.config["system"] = "van_der_pol"
+    cell.config["merinda"]["dt"] = 0.02
+    cell.traffic["sas_gains"] = [0.0, -0.1]
+    cell.physics = physics_for(cell.config,
+                               Path(__file__).parent / "van_der_pol.json")
+    return cell
+
+
+def run(cell: str | Cell, **kw) -> dict:
+    cell = small(cell) if isinstance(cell, str) else cell
+    return run_cell(cell, SEED, 1.5, False, require_chip=False,
                     log=lambda s: None, **kw)
 
 
@@ -94,3 +110,20 @@ def test_scenario_answer_altered_where_produced(monkeypatch):
     monkeypatch.setattr(ScenarioRunner, "rollout", nudged)
     result = run("online64.whatif")
     assert "scenario" in failing(result), result["compared"]
+
+
+def test_second_family_sound_program_is_correct():
+    result = run(van_der_pol())
+    assert result["compared"]["ring"]["value"] == 0.0, result["compared"]
+    assert result["correct"], result["compared"]
+
+
+def test_second_family_step_that_returns_its_state_unchanged(monkeypatch):
+    orig = FleetMerinda.train_step_per_slot
+
+    def frozen(self, state, y_win, u_win):
+        _, loss, ok = orig(self, state, y_win, u_win)
+        return state, loss, ok
+    monkeypatch.setattr(FleetMerinda, "train_step_per_slot", frozen)
+    result = run(van_der_pol())
+    assert "step" in failing(result), result["compared"]

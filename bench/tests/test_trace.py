@@ -80,9 +80,19 @@ def test_hand_worked_counts():
     # per step: 2*4*6 + 2*2*4 + 2*2*2 + 10*2 = 92; 2 rows x 3 steps
     assert flops.gru_flops(2, 3, 4, 2) == 552
     assert flops.gru_bytes(2, 3, 4, 2) == 4 * (24 + 4 + 42 + 12 + 4)
-    # L = 35; per stage 2*35 + 2*3*35 + 6 = 286; 4 stages + 18
-    assert flops.rk4_flops(1, 1) == 1162
-    assert flops.rk4_bytes(1, 1) == 4 * (105 + 3 + 1 + 6)
+    # the F-8: L = 35; per stage 2*35 + 2*3*35 + 6 = 286; 4 stages + 18
+    f8 = {"order": 3, "n": 3, "m": 1}
+    assert flops.rk4_flops(1, 1, **f8) == 1162
+    assert flops.rk4_bytes(1, 1, **f8) == 4 * (105 + 3 + 1 + 6)
+
+
+def test_counts_for_the_van_der_pol_dimensions():
+    # L = C(6, 3) = 20; per stage 2*20 + 2*2*20 + 2*2 = 124; 4 stages + 12
+    vdp = {"order": 3, "n": 2, "m": 1}
+    assert flops.library_size(3, 3) == 20
+    assert flops.rk4_flops(1, 1, **vdp) == 508
+    # theta 2 x 20, y0 2, one input, two states out at t = 0 and 1
+    assert flops.rk4_bytes(1, 1, **vdp) == 4 * (40 + 2 + 1 + 4)
 
 
 def test_roofline_share_and_bound():
